@@ -210,6 +210,13 @@ class GolRuntime:
     metrics_port: Optional[int] = None
 
     def __post_init__(self) -> None:
+        from gol_tpu import telemetry as telemetry_mod
+
+        with telemetry_mod.trace_annotation("gol.runtime.init"):
+            self._configure()
+
+    def _configure(self) -> None:
+        """Validate the knobs, resolve the engine and check the mesh."""
         if self.engine not in ENGINES:
             raise ValueError(f"unknown engine {self.engine!r}; expected {ENGINES}")
         if self.halo_mode not in engine_mod.HALO_MODES:
@@ -1187,8 +1194,6 @@ class GolRuntime:
         (:func:`gol_tpu.telemetry.stats.build_stats_evolver`) returning
         ``(board, stats)``; off, this path is byte-for-byte the PR 2 one.
         """
-        import time as time_mod
-
         from gol_tpu import telemetry as telemetry_mod
 
         if self.mesh is not None:
@@ -1225,26 +1230,38 @@ class GolRuntime:
                 static = ()
             else:
                 fn, dynamic, static = self._evolve_fn(take)
-            from gol_tpu.batch import cache as cache_mod
-
-            probe = cache_mod.CompileCacheProbe()
-            with telemetry_mod.trace_annotation(f"gol.compile.{take}"):
-                t0 = time_mod.perf_counter()
-                lowered = fn.lower(*specs, *dynamic, *static)
-                t1 = time_mod.perf_counter()
-                compiled = lowered.compile()
-                t2 = time_mod.perf_counter()
-            evolvers[take] = (compiled, dynamic)
+            probe = None
             if events is not None:
+                from gol_tpu.batch import cache as cache_mod
+
+                probe = cache_mod.CompileCacheProbe()
+            # gol.compile less its gol.lower child is the XLA compile or
+            # the persistent-cache read; the counters say which.
+            with telemetry_mod.trace_annotation(f"gol.compile.{take}") as span:
+                with telemetry_mod.trace_annotation(
+                    f"gol.lower.{take}"
+                ) as lower:
+                    lowered = fn.lower(*specs, *dynamic, *static)
+                compiled = lowered.compile()
+                cache = telemetry_mod.cache_outcome(span.counts)
+                if cache is not None:
+                    span.attrs["cache"] = cache
+            evolvers[take] = (compiled, dynamic)
+            if probe is not None:
                 from gol_tpu.telemetry import stats as stats_mod
 
-                cache_hit, cache_key = probe.resolve()
+                # Hit or miss as the span's counters saw it; the probe
+                # only says whether a cache is on and names the entry a
+                # miss wrote.
+                _, cache_key = probe.resolve()
                 events.compile_event(
                     take,
-                    t1 - t0,
-                    t2 - t1,
+                    lower.seconds,
+                    span.t1 - lower.t1,
                     memory=stats_mod.compiled_memory(compiled),
-                    cache_hit=cache_hit,
+                    cache_hit=None
+                    if probe.directory is None
+                    else cache == "hit",
                     cache_key=cache_key,
                 )
         force_ready(board)
@@ -1709,23 +1726,27 @@ class GolRuntime:
         self.last_stats = []
         self.last_activity = []
         self._ckpt_shed = False
-        with sw.phase("init"):
-            state = self.initial_state(pattern, resume)
-            board = state.board
-            act_mask = (
-                self._initial_activity_mask()
-                if self._resolved == "activity"
-                else None
+        with telemetry_mod.trace_annotation("gol.init"):
+            with sw.phase("init"):
+                state = self.initial_state(pattern, resume)
+                board = state.board
+                act_mask = (
+                    self._initial_activity_mask()
+                    if self._resolved == "activity"
+                    else None
+                )
+
+            # Chunk schedule: full chunks of `checkpoint_every` plus one
+            # tail.
+            schedule = self.chunk_schedule(
+                iterations,
+                self.checkpoint_every
+                if self.checkpoint_every > 0
+                else iterations,
             )
 
-        # Chunk schedule: full chunks of `checkpoint_every` plus one tail.
-        schedule = self.chunk_schedule(
-            iterations,
-            self.checkpoint_every if self.checkpoint_every > 0 else iterations,
-        )
-
-        if self.mesh is not None:
-            board = mesh_mod.shard_board(board, self.mesh)
+            if self.mesh is not None:
+                board = mesh_mod.shard_board(board, self.mesh)
 
         events = self.open_event_log()
         self._live_events = events
@@ -1765,14 +1786,15 @@ class GolRuntime:
                         dev_act = None
                         with telemetry_mod.step_annotation("gol.chunk", i):
                             with sw.phase("total"):
-                                t0 = time_mod.perf_counter()
-                                if act_mask is not None:
-                                    out = compiled(
-                                        board, act_mask, *dynamic
-                                    )
-                                else:
-                                    out = compiled(board, *dynamic)
-                                t1 = time_mod.perf_counter()
+                                with telemetry_mod.trace_annotation(
+                                    "gol.dispatch"
+                                ) as dispatch:
+                                    if act_mask is not None:
+                                        out = compiled(
+                                            board, act_mask, *dynamic
+                                        )
+                                    else:
+                                        out = compiled(board, *dynamic)
                                 if act_mask is not None:
                                     if self.stats:
                                         (board, act_mask, dev_act,
@@ -1784,14 +1806,18 @@ class GolRuntime:
                                         board, dev_stats = out
                                     else:
                                         board = out
-                                force_ready(board)
-                                dt = time_mod.perf_counter() - t0
+                                with telemetry_mod.trace_annotation(
+                                    "gol.fence"
+                                ) as fence:
+                                    force_ready(board)
+                            dt = fence.t1 - dispatch.t0
                         if sc is not None:
                             # dispatch = enqueue until the async call
                             # returns; ready = the block_until_ready
                             # fence.  Together they partition wall_s.
-                            sc.add("dispatch", t1 - t0)
-                            sc.add("ready", dt - (t1 - t0))
+                            # Timed once, by the spans.
+                            sc.add("dispatch", dispatch.seconds)
+                            sc.add("ready", dt - dispatch.seconds)
                         if plan_on:
                             # Fault-plane SDC injection (board.bitflip):
                             # a host-side functional cell update between
@@ -1801,9 +1827,12 @@ class GolRuntime:
                             board = faults_mod.apply_board_faults(
                                 board, int(state.generation) + take
                             )
-                        state = GolState.create(
-                            board, int(state.generation) + take
-                        )
+                        # Reads the generation back from the device and
+                        # puts the next one there.
+                        with telemetry_mod.trace_annotation("gol.advance"):
+                            state = GolState.create(
+                                board, int(state.generation) + take
+                            )
                         act_block = None
                         if dev_act is not None:
                             # Scalar fetch after the timed fence, like

@@ -27,7 +27,9 @@ of it:
   not per run; off the TPU the stamp is ``None``.
 - :func:`step_annotation` / :func:`trace_annotation` wrap the host-side
   loop bodies in ``jax.profiler`` annotations so ``--profile`` traces are
-  navigable (named chunks/audits/saves) instead of anonymous.
+  navigable (named chunks/audits/saves) instead of anonymous, and record
+  each span in an always-on, bounded in-memory span log
+  (:func:`span_log`) with the compile-path counters of ``jax.monitoring``.
 - ``python -m gol_tpu.telemetry summarize <dir>`` merges rank files,
   renders per-phase/per-chunk tables with the roofline column, and flags
   anomalies; ``diff`` compares two runs (:mod:`gol_tpu.telemetry.
@@ -57,9 +59,11 @@ catch any callback that leaked inside).
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import os
+import threading
 import time
 from typing import Dict, Optional
 
@@ -901,24 +905,198 @@ def roofline_utilization_3d(
     return (lane_ops / wall_s) / roofline.vpu_lane_ops()
 
 
-# -- jax.profiler annotations (host-side; no-ops unless a trace is live) ----
+# -- program spans: profiler annotations and the in-memory span log --------
+#
+# A span opened by trace_annotation / step_annotation opens a
+# jax.profiler annotation (on the profiler's host plane, on the device
+# trace's clock, while a trace is live) and, when it closes, lands in
+# the process-wide span log: always on, in memory, host-side only.  The
+# log keeps exact per-name aggregates that never drop (count, total
+# seconds, self seconds -- the duration less that of the child spans it
+# encloses -- and the counters that fired while the span was innermost)
+# and the newest SPAN_LOG_CAPACITY records: name, start and end on
+# time.perf_counter, the enclosing open span on the same thread, attrs
+# and counters.  ``dropped`` counts the records that fell off the front.
+# Read it with span_log(); docs/OBSERVABILITY.md lists the spans.
+
+#: Records the span log keeps; older ones fall off into ``dropped``.
+SPAN_LOG_CAPACITY = 4096
+#: jax.monitoring duration events counted against the innermost open
+#: span: tracing, lowering and XLA compiles, and persistent-cache reads.
+COMPILE_EVENT_PREFIXES = ("/jax/core/compile/", "/jax/compilation_cache/")
+#: Fires on a persistent-cache hit, and only then...
+CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: ...and this one around every XLA compile, a cache read included.
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_span_lock = threading.Lock()
+_span_records: collections.deque = collections.deque(maxlen=SPAN_LOG_CAPACITY)
+_span_totals: Dict[str, list] = {}  # name -> [count, total_s, self_s, counts]
+_counters: Dict[str, list] = {}  # name -> [n, seconds]
+_listening = False
 
 
-def step_annotation(name: str, step: int):
-    """``StepTraceAnnotation`` for one chunk — numbered steps in xprof."""
+class _OpenSpans(threading.local):
+    def __init__(self) -> None:
+        self.stack: list = []  # this thread's open spans, innermost last
+
+
+_open_spans = _OpenSpans()
+
+
+def _add(into: dict, name: str, n: int, seconds: float) -> None:
+    c = into.get(name)
+    if c is None:
+        into[name] = [n, seconds]
+    else:
+        c[0] += n
+        c[1] += seconds
+
+
+class _Span:
+    """One span: its profiler annotation while open, its record after.
+    ``t0``/``t1``/``seconds`` are readable once it has closed, so a
+    caller that needs the interval times it once, through the span."""
+
+    __slots__ = ("name", "attrs", "counts", "parent", "thread", "t0", "t1",
+                 "_child_s", "_annotation")
+
+    def __init__(self, name: str, annotation, attrs: dict) -> None:
+        self.name = name
+        self.attrs = attrs
+        self.counts: Optional[Dict[str, list]] = None  # made by count()
+        self._child_s = 0.0
+        self._annotation = annotation
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+    def __enter__(self) -> "_Span":
+        if not _listening:
+            _listen()
+        self._annotation.__enter__()
+        stack = _open_spans.stack
+        self.parent = stack[-1] if stack else None
+        self.thread = threading.get_ident()
+        stack.append(self)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.t1 = time.perf_counter()
+        _open_spans.stack.pop()
+        seconds = self.t1 - self.t0
+        if self.parent is not None:
+            self.parent._child_s += seconds
+        with _span_lock:
+            _span_records.append(self)
+            tot = _span_totals.get(self.name)
+            if tot is None:
+                tot = _span_totals[self.name] = [0, 0.0, 0.0, {}]
+            tot[0] += 1
+            tot[1] += seconds
+            tot[2] += seconds - self._child_s
+            if self.counts:
+                for name, (n, s) in self.counts.items():
+                    _add(tot[3], name, n, s)
+        self._annotation.__exit__(exc_type, exc, tb)
+
+    def record(self) -> dict:
+        return {
+            "name": self.name,
+            "t0": self.t0,
+            "t1": self.t1,
+            "parent": None if self.parent is None else self.parent.name,
+            "thread": self.thread,
+            "attrs": dict(self.attrs),
+            "counts": {k: list(v) for k, v in (self.counts or {}).items()},
+        }
+
+
+def step_annotation(name: str, step: int) -> _Span:
+    """A span for one chunk: a numbered ``StepTraceAnnotation`` in xprof,
+    and a log record with attr ``step``."""
     import jax
 
-    try:
-        return jax.profiler.StepTraceAnnotation(name, step_num=step)
-    except AttributeError:  # pragma: no cover - profiler API absent
-        return contextlib.nullcontext()
+    return _Span(
+        name, jax.profiler.StepTraceAnnotation(name, step_num=step),
+        {"step": step},
+    )
 
 
-def trace_annotation(name: str):
-    """Named ``TraceAnnotation`` span (compile, audit, checkpoint save)."""
+def trace_annotation(name: str, **attrs) -> _Span:
+    """A named span (compile, dispatch, fence, audit, checkpoint save): a
+    ``TraceAnnotation`` in xprof, and a log record carrying ``attrs``
+    (the span's ``attrs`` dict may be added to while it is open)."""
     import jax
 
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except AttributeError:  # pragma: no cover - profiler API absent
-        return contextlib.nullcontext()
+    return _Span(name, jax.profiler.TraceAnnotation(name), attrs)
+
+
+def count(name: str, n: int = 1, seconds: float = 0.0) -> None:
+    """Count ``n`` occurrences of ``name`` (taking ``seconds``) in the
+    process-wide counters and against this thread's innermost open
+    span."""
+    stack = _open_spans.stack
+    if stack:
+        span = stack[-1]
+        if span.counts is None:
+            span.counts = {}
+        _add(span.counts, name, n, seconds)
+    with _span_lock:
+        _add(_counters, name, n, seconds)
+
+
+def _on_jax_duration(event: str, duration: float, **_) -> None:
+    if event.startswith(COMPILE_EVENT_PREFIXES):
+        count(event, 1, duration)
+
+
+def _listen() -> None:
+    """Register the compile-path counters once, at the first span."""
+    global _listening
+    with _span_lock:
+        if _listening:
+            return
+        _listening = True
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def cache_outcome(counts: Optional[dict]) -> Optional[str]:
+    """``"hit"`` where a span's counters show a persistent-cache read,
+    ``"miss"`` where XLA compiled without one, None where neither ran."""
+    counts = counts or {}
+    if CACHE_READ_EVENT in counts:
+        return "hit"
+    if BACKEND_COMPILE_EVENT in counts:
+        return "miss"
+    return None
+
+
+def span_log() -> dict:
+    """A consistent copy of the span log: ``records`` (oldest first, as
+    dicts), per-name ``totals`` ({count, total_s, self_s, counts}),
+    process-wide ``counters`` ({name: [n, seconds]}), ``recorded``,
+    ``dropped`` and ``capacity``."""
+    with _span_lock:
+        spans = list(_span_records)
+        recorded = sum(tot[0] for tot in _span_totals.values())
+        totals = {
+            name: {
+                "count": n, "total_s": total, "self_s": own,
+                "counts": {k: list(v) for k, v in counts.items()},
+            }
+            for name, (n, total, own, counts) in _span_totals.items()
+        }
+        counters = {k: list(v) for k, v in _counters.items()}
+    return {
+        "capacity": SPAN_LOG_CAPACITY,
+        "recorded": recorded,
+        "dropped": recorded - len(spans),
+        "records": [s.record() for s in spans],
+        "totals": totals,
+        "counters": counters,
+    }
