@@ -11,24 +11,34 @@ The XLA lowering of the pure-jnp packed step materializes the bit-plane
 temporaries between fusions, so it runs far below both VPU and HBM peak.
 Here the entire adder tree + rule runs fused over one VMEM tile, and
 generations are **temporally blocked**: each kernel launch loads its tile
-with a k-deep halo pad and evolves k generations in VMEM (the valid window
-shrinking one row per side per step), so k generations cost one HBM round
-trip and one launch instead of k.  The window DMAs are **double-buffered
-across grid steps** (tile i+1's three mod-H fetches issued into a second
-scratch slot before tile i's adder tree): best-of-8 samples at
+with a k-deep halo pad and evolves k generations in VMEM (the exact rows
+shrinking one row per side per step inside a window that stays put), so
+k generations cost one HBM round trip and one launch instead of k.  The
+window DMAs are **double-buffered across grid steps** (tile i+1's three
+mod-H fetches issued into a second scratch slot before tile i's adder
+tree): best-of-8 samples at
 16384²×1024 measure 8.96/9.77e11 cell-updates/s vs 8.20/8.69e11 for the
 serial-DMA form — ~10% from hiding the input fetch under the VPU work.
 Earlier same-session sweep (k=16, tile=256, serial DMA): ~8.6e11 vs
 ~7.3e11 for the k=1 kernel (+17%); the kernel is VPU-bound (~22 bitwise
 ops per 32-cell word), which is why deeper blocking saturates — the
-recomputed halo bands add ~2k/tile extra compute.  A fully VMEM-resident
-variant (no HBM traffic at all, row wrap via sublane rolls) measured 3×
-*slower* per cell — sublane rolls beat slicing-with-halo-pad nowhere.
+recomputed halo bands add ~2k/tile extra compute.
+
+**Every generation steps the whole aligned window in place**, the rows
+above and below coming from sublane rolls (:func:`_one_generation`).
+Slicing a shrinking window at odd row offsets instead made Mosaic
+relayout every misaligned value: the post-finalize LLO of one grid step
+at tile 256, k 8 counted 58,588 VALU ops (16,412 of them selects) and
+14,704 sublane rotates; the aligned window with the count-of-8 B3/S23
+tail counts 36,992 and 4,352 (``scripts/kernel_llo_tally.py``).  An
+earlier fully VMEM-resident variant that rolled the whole board's height
+measured 3× *slower* per cell; that was the whole-board kernel, not rolls
+over one tile's window.
 
 Mosaic notes: compute is int32 (bit-identical to uint32 for the bitwise
-adder ops — the adder/rule algebra itself is reused from
-``bitlife._full_add`` / ``bitlife._rule_from_row_sums``); logical right
-shifts are emulated with arithmetic shift + mask (``_lsr``); the word-ring
+adder ops — the adder algebra itself is reused from
+``bitlife._full_add``); logical right shifts are
+``lax.shift_right_logical`` (Mosaic's ``arith.shrui``); the word-ring
 column wrap (gol-with-cuda.cu:210-211) is a ``pltpu.roll`` along lanes,
 carry bits crossing words via shifts exactly as in ``bitlife._west_east``.
 Row wrap is handled at DMA time with mod-H aligned halo fetches
@@ -104,19 +114,24 @@ def fold_feasible(
     )
 
 
-def _lsr(x: jax.Array, r: int) -> jax.Array:
-    """Logical shift right on int32 lanes (mask off the sign extension)."""
-    return (x >> r) & jnp.int32((1 << (32 - r)) - 1)
-
-
 def _one_generation(ext: jax.Array, rule=None, groups: int = 1) -> jax.Array:
-    """One packed generation over an extended row window (shrinks by 2 rows).
+    """One packed generation over a whole row window, same shape out.
 
-    Per-row 3-cell horizontal sums once per extended row (bit planes),
-    column wrap via a lane roll with carry bits crossing words by shifts.
-    ``rule=None`` runs the hard-wired B3/S23 tail (the reference's rule,
-    two ops cheaper); a ``Rule2D`` runs the generic plane matcher on the
-    count-of-9 with the +1 survive identity (see
+    Per-row 3-cell horizontal sums once per row (bit planes), column wrap
+    via a lane roll with carry bits crossing words by shifts.  The rows
+    above and below come from sublane rolls of those sums, so every load,
+    store and elementwise op stays (8, 128)-aligned when the window is a
+    whole number of 8-row tiles.  The window's first and last rows read
+    the opposite edge through the roll and come out wrong: after ``j``
+    generations the outer ``j`` rows on each side are garbage, which the
+    callers keep inside a ghost band at least as deep as the generations
+    they step.
+
+    ``rule=None`` runs the hard-wired B3/S23 tail on the count of the 8
+    neighbours: the horizontal full adder is ordered so its first half
+    adder is the middle row's neighbour-only sum, and a count of 8 reads
+    0 in both planes the tail tests.  A ``Rule2D`` runs the generic plane
+    matcher on the count-of-9 with the +1 survive identity (see
     :func:`gol_tpu.ops.rules.step_rule_packed`).
 
     ``groups > 1`` is the lane-folded narrow-shard layout (``groups`` row
@@ -125,7 +140,7 @@ def _one_generation(ext: jax.Array, rule=None, groups: int = 1) -> jax.Array:
     word takes its carry from its *own* group's opposite edge (two masked
     rolls), so the fold introduces no seam wrongness at all and the
     row-sharded engine needs no repair.  Cost: 2 extra rolls + 2 selects
-    per extended row per generation (~18% on the ~22-op tree).
+    per row per generation (~13% on the 30-op generation).
     """
     nw = ext.shape[1]
     if groups == 1:
@@ -149,18 +164,26 @@ def _one_generation(ext: jax.Array, rule=None, groups: int = 1) -> jax.Array:
             pltpu.roll(ext, gw - 1, axis=1),
             pltpu.roll(ext, nw - 1, axis=1),
         )
-    west = (ext << 1) | _lsr(prev_word, 31)
-    east = _lsr(ext, 1) | (next_word << 31)
-    s0, s1 = bitlife._full_add(west, ext, east)
-    sa = (s0[:-2], s1[:-2])
-    sc = (s0[1:-1], s1[1:-1])
-    sb = (s0[2:], s1[2:])
+    west = (ext << 1) | lax.shift_right_logical(prev_word, 31)
+    east = lax.shift_right_logical(ext, 1) | (next_word << 31)
+    h0 = west ^ east
+    h1 = west & east
+    s0 = h0 ^ ext
+    s1 = h1 | (ext & h0)
+    rows = ext.shape[0]
+    sa = (pltpu.roll(s0, 1, axis=0), pltpu.roll(s1, 1, axis=0))  # above
+    sb = (pltpu.roll(s0, rows - 1, axis=0), pltpu.roll(s1, rows - 1, axis=0))
     if rule is None:
-        return bitlife._rule_from_row_sums(ext[1:-1], sa, sc, sb)
+        # above + below + middle neighbours: count = l0 + 2*a1 + 4*a2 (+8)
+        l0, c0 = bitlife._full_add(sa[0], sb[0], h0)
+        u, v = bitlife._full_add(sa[1], sb[1], h1)
+        a1 = u ^ c0
+        a2 = v ^ (u & c0)
+        return (l0 | ext) & a1 & ~a2  # a count of 3, or of 2 and alive
     from gol_tpu.ops.rules import _rule_from_count9
 
     return _rule_from_count9(
-        ext[1:-1], bitlife._sum3_2bit(sa, sc, sb), rule
+        ext, bitlife._sum3_2bit(sa, (s0, s1), sb), rule
     )
 
 
@@ -170,13 +193,15 @@ def _kernel(
 ):
     """k torus generations per VMEM residency (temporal blocking).
 
-    The tile is loaded with a k-deep halo pad on each side; generation j
-    evolves the window ``[pad-(k-j), pad+tile+(k-j))`` in place, shrinking
-    the valid region by one row per side per step, so after k steps the
-    body tile is exact.  Neighboring tiles recompute the overlapping halo
-    bands independently — the in-kernel analog of the sharded engines'
-    ``--halo-depth`` temporal blocking, trading O(k²) duplicated edge rows
-    for k× fewer HBM round trips and kernel launches.
+    The tile is loaded with a ``pad``-deep halo (``pad >= k``, a multiple
+    of 8) on each side; every generation evolves the whole aligned
+    ``tile + 2*pad`` window in place, and the wrong rows the window's
+    edges make grow inward one row per generation, so after k steps they
+    stay inside the pad and the body tile is exact.  Neighboring tiles
+    recompute the overlapping halo bands independently — the in-kernel
+    analog of the sharded engines' ``--halo-depth`` temporal blocking,
+    trading O(k²) duplicated edge rows for k× fewer HBM round trips and
+    kernel launches.
 
     Like :func:`_kernel_ext`, the three window DMAs are double-buffered
     across grid steps: tile ``i+1``'s mod-H fetches are issued into the
@@ -193,12 +218,8 @@ def _kernel(
         )
 
     load_window_double_buffered(copies, i, i + 1, slot, i == 0, i + 1 < nt)
-    for j in range(k):
-        a = pad - (k - j)
-        b = pad + tile + (k - j)
-        scratch[slot, a + 1 : b - 1] = _one_generation(
-            scratch[slot, a:b], rule
-        )
+    for _ in range(k):
+        scratch[slot] = _one_generation(scratch[slot], rule)
     out_ref[:] = scratch[slot, pad : pad + tile]
 
 
@@ -351,15 +372,12 @@ def _evolve_window_and_store(
     groups: int = 1,
 ):
     """The ext kernels' shared compute tail: k in-place generations over
-    the slot's window (shrinking one row per side per step), body store,
-    and the optional exact-edge-word overwrite (see
+    the slot's whole ``tile + 2k`` window (its k ghost rows a side hold
+    the rows :func:`_one_generation` gets wrong), body store, and the
+    optional exact-edge-word overwrite (see
     :func:`multi_step_pallas_packed_ext`)."""
-    for j in range(k):
-        a = j
-        b = tile + 2 * k - j
-        scratch[slot, a + 1 : b - 1] = _one_generation(
-            scratch[slot, a:b], rule, groups
-        )
+    for _ in range(k):
+        scratch[slot] = _one_generation(scratch[slot], rule, groups)
     out_ref[:] = scratch[slot, k : k + tile]
     if edges_ref is not None:
         # One (left, right) exact column pair per lane-fold row group —

@@ -55,7 +55,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from gol_tpu.ops import bitlife, bitlife3d, pallas_common
 from gol_tpu.ops.life3d import BAYS_4555, Rule3D
-from gol_tpu.ops.pallas_bitlife import _lsr, _pick_block
+from gol_tpu.ops.pallas_bitlife import _pick_block
 from gol_tpu.ops.pallas_common import (
     load_tile_with_halo,
     load_window_double_buffered,
@@ -77,8 +77,8 @@ def _one_generation(
     h = ext.shape[2]
     prev_w = jnp.concatenate([ext[:, -1:], ext[:, :-1]], axis=1)
     next_w = jnp.concatenate([ext[:, 1:], ext[:, :1]], axis=1)
-    west = (ext << 1) | _lsr(prev_w, 31)
-    east = _lsr(ext, 1) | (next_w << 31)
+    west = (ext << 1) | lax.shift_right_logical(prev_w, 31)
+    east = lax.shift_right_logical(ext, 1) | (next_w << 31)
     s0, s1 = bitlife._full_add(west, ext, east)
     count9 = bitlife._sum3_2bit(
         (pltpu.roll(s0, 1, axis=2), pltpu.roll(s1, 1, axis=2)),
@@ -116,8 +116,8 @@ def _count9_plane(plane: jax.Array, wrap: bool = True):
         zero = jnp.zeros_like(plane[:1])
         prev_w = jnp.concatenate([zero, plane[:-1]], axis=0)
         next_w = jnp.concatenate([plane[1:], zero], axis=0)
-    west = (plane << 1) | _lsr(prev_w, 31)
-    east = _lsr(plane, 1) | (next_w << 31)
+    west = (plane << 1) | lax.shift_right_logical(prev_w, 31)
+    east = lax.shift_right_logical(plane, 1) | (next_w << 31)
     s0, s1 = bitlife._full_add(west, plane, east)
     return bitlife._sum3_2bit(
         (pltpu.roll(s0, 1, axis=1), pltpu.roll(s1, 1, axis=1)),
@@ -537,8 +537,8 @@ def _one_generation_wt(
     zero = jnp.zeros_like(ext[:1])
     prev_w = jnp.concatenate([zero, ext[:-1]], axis=0)
     next_w = jnp.concatenate([ext[1:], zero], axis=0)
-    west = (ext << 1) | _lsr(prev_w, 31)
-    east = _lsr(ext, 1) | (next_w << 31)
+    west = (ext << 1) | lax.shift_right_logical(prev_w, 31)
+    east = lax.shift_right_logical(ext, 1) | (next_w << 31)
     s0, s1 = bitlife._full_add(west, ext, east)
     count9 = bitlife._sum3_2bit(
         (pltpu.roll(s0, 1, axis=2), pltpu.roll(s1, 1, axis=2)),

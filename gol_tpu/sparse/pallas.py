@@ -22,9 +22,9 @@ Gating granularity is one row *band* of tiles (= ``tile`` board rows):
 band i is live iff any tile in mask row i is dilated-active
 (:func:`gol_tpu.sparse.mask.band_mask`).  The three shifted input views
 (band above / center / below, torus-wrapped block index maps) give the
-kernel its ±1 ghost rows, so an active band next to an inactive one
-still reads fresh neighbor rows — the same one-generation-per-call
-contract as :func:`gol_tpu.ops.bitlife.step_packed`.
+kernel an aligned 8-row ghost band a side, so an active band next to an
+inactive one still reads fresh neighbor rows — the same
+one-generation-per-call contract as :func:`gol_tpu.ops.bitlife.step_packed`.
 
 Like every Pallas tier, bit-identity is the contract: interpret mode
 (any backend) pins this kernel against the jnp packed step in
@@ -51,11 +51,13 @@ def _kernel(mask_ref, above, center, below, out_ref):
 
     @pl.when(mask_ref[i] != 0)
     def _():
+        # An aligned 8-row ghost band a side: the generation gets only
+        # the window's outer row wrong.
         band = center.shape[0]
         ext = jnp.concatenate(
-            [above[band - 1 : band], center[:], below[0:1]], axis=0
+            [above[band - _ALIGN : band], center[:], below[0:_ALIGN]], axis=0
         )
-        out_ref[:] = _one_generation(ext)
+        out_ref[:] = _one_generation(ext)[_ALIGN : _ALIGN + band]
 
     @pl.when(mask_ref[i] == 0)
     def _():

@@ -23,28 +23,36 @@ count.
 
 **Audited op counts** (per 32-cell word, per generation):
 
-2-D B3/S23 kernel (:func:`gol_tpu.ops.pallas_bitlife._one_generation`):
-  - horizontal stage, per *extended* row: 2 lane rolls + west (shift,
-    shift, mask, or = 4) + east (4) + full-adder (2 xor + 2 and + 1 or
-    = 5) = **15**;
-  - rule tail, per *output* row: ``_sum3_2bit`` (2 full adders + 4 = 14)
-    + ``eq3`` (4) + ``eq4`` (6) + combine (2) = **26**;
-  - lane-folded variant (``groups > 1``): + 2 rolls + 2 selects per
-    extended row = **19**/15.
+2-D B3/S23 kernel (:func:`gol_tpu.ops.pallas_bitlife._one_generation`),
+per row of the window, every row of it every generation:
+  - horizontal stage: 2 lane rolls + west (shift, logical shift, or = 3)
+    + east (3) + full adder through the neighbour-only half adder (2 xor
+    + 2 and + 1 or = 5) = **13**;
+  - rule tail: two full adders on the count of 8 (10) + its twos and
+    fours planes (3) + ``(ones | centre) & twos & ~fours`` (4) = **17**.
+    The 4 sublane rolls that bring the rows above and below are not
+    counted, as the row slices they replaced were not;
+  - lane-folded variant (``groups > 1``): + 2 rolls + 2 selects per row
+    = **17**/17.
+
+The post-finalize LLO of one grid step at tile 256, k 8 agrees: 34 VALU
+ops a vreg, the 28 above plus 6 selects that merge the rotates across
+vregs (``scripts/kernel_llo_tally.py``).
 
 3-D Bays-4555 word-tiled kernel
 (:func:`gol_tpu.ops.pallas_bitlife3d._one_generation_wt`), per window
-word: x stage (2 shifts + 4 + 4 + 5) = 15; count-of-9 (4 lane rolls +
+word: x stage (2 shifts + 3 + 3 + 5) = 13; count-of-9 (4 lane rolls +
 14) = 18; count-of-27 (``_sum3_planes`` width 5: 4 full adders = 20 +
 zero-folded ripple ~19) = 39; count-of-26 (``_sub_bit`` over 5 planes,
 zero folds) = 13; rule match (B={5}: ~8, S={4,5}: ~17) = 25; combine 5 —
-**115** total.
+**113** total.
 
 The temporal blocking recomputes halo bands: a tile of ``t`` rows stepped
-``k`` generations computes windows of ``t + 2(k-j)`` rows at step ``j``,
-so useful output pays a ``(t + k + 1)/t``-ish multiplier (exact sums
-below); the 3-D word tiling additionally pays ``(tw + 2)/tw`` on the word
-axis and ``(td + 2*pad)/td`` on the plane axis.
+``k`` generations steps its whole aligned window of ``t + 2*pad`` rows
+(``pad``: k rounded up to 8) every generation, so useful output pays a
+``(t + 2*pad)/t`` multiplier; the 3-D word tiling additionally pays
+``(tw + 2)/tw`` on the word axis and ``(td + 2*pad)/td`` on the plane
+axis.
 """
 
 from __future__ import annotations
@@ -157,11 +165,11 @@ def xla_bytes_model(engine: str, shard_cells: int) -> float:
 
 
 # 2-D B3/S23 fused kernel, per word (see module docstring for the audit).
-OPS_2D_HSUM_PER_EXT_ROW = 15
-OPS_2D_HSUM_PER_EXT_ROW_FOLDED = 19
-OPS_2D_RULE_PER_OUT_ROW = 26
+OPS_2D_HSUM_PER_EXT_ROW = 13
+OPS_2D_HSUM_PER_EXT_ROW_FOLDED = 17
+OPS_2D_RULE_PER_OUT_ROW = 17
 # 3-D Bays-4555 word-tiled kernel, per window word per generation.
-OPS_3D_WT_PER_WORD = 115
+OPS_3D_WT_PER_WORD = 113
 
 BITS = 32  # cells per packed word
 
@@ -187,23 +195,21 @@ class Roofline:
 def ops_2d_per_useful_word(tile: int, k: int, folded: bool = False) -> float:
     """Mean emitted ops per useful output word of the 2-D fused kernel.
 
-    A ``tile``-row window stepped ``k`` generations runs the horizontal
-    stage over ``tile + 2(k-j)`` rows and the rule tail over two fewer, at
-    step ``j``; useful output is ``tile * k`` word-rows.
+    Each of the ``k`` generations runs both stages over the whole window
+    (:func:`recompute_2d`); useful output is ``tile * k`` word-rows.
     """
     h_ops = (
         OPS_2D_HSUM_PER_EXT_ROW_FOLDED if folded else OPS_2D_HSUM_PER_EXT_ROW
     )
-    total = 0.0
-    for j in range(k):
-        window = tile + 2 * (k - j)
-        total += window * h_ops + (window - 2) * OPS_2D_RULE_PER_OUT_ROW
-    return total / (tile * k)
+    return recompute_2d(tile, k) * (h_ops + OPS_2D_RULE_PER_OUT_ROW)
 
 
 def recompute_2d(tile: int, k: int) -> float:
-    """Windowed rows / useful rows for the 2-D temporal blocking."""
-    return sum(tile + 2 * (k - j) for j in range(k)) / (tile * k)
+    """Windowed rows / useful rows for the 2-D temporal blocking: every
+    generation steps ``tile + 2*pad`` rows, ``pad`` being k rounded up to
+    the kernels' 8-row tiling."""
+    pad = -(-k // 8) * 8
+    return (tile + 2 * pad) / tile
 
 
 def roofline_2d(

@@ -11,14 +11,21 @@ halo exchange.
 
 The topology is described inside a module fixture, never at import: one
 process at a time may load the TPU library, and every xdist worker
-imports this file.  The kernels ask :func:`gol_tpu.ops.pallas_common.
-interpret` whether to interpret; the fixture answers "no" for the
-duration of these tests, since this process's backend is the CPU.
+imports this file.  ``scripts/kernel_llo_tally.py`` compiles in a child
+process (Mosaic's dump flag is read when the library loads), so its
+fixture runs first, before this process loads the library.  The kernels
+ask :func:`gol_tpu.ops.pallas_common.interpret` whether to interpret;
+the fixture answers "no" for the duration of these tests, since this
+process's backend is the CPU.
 """
 
 from __future__ import annotations
 
+import json
 import os
+import pathlib
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -32,8 +39,29 @@ BOARD = 16384  # BASELINE config 3, the smoke's CLI / soup / ring board
 VOLUME = 512  # config 5 cut to one chip, the smoke's 3-D phase
 
 
+TALLY = pathlib.Path(__file__).resolve().parent.parent / "scripts" / (
+    "kernel_llo_tally.py"
+)
+
+
 @pytest.fixture(scope="module")
-def topo():
+def llo_tally():
+    """The flagship grid step's LLO tally, compiled in a child process
+    that has to load the TPU library before this one does."""
+    from scripts import kernel_llo_tally
+
+    run = subprocess.run(
+        [sys.executable, str(TALLY), "--json"],
+        capture_output=True, text=True, timeout=600,
+    )
+    if run.returncode == kernel_llo_tally.NO_TOPOLOGY:
+        pytest.skip(run.stderr.strip().splitlines()[-1])
+    assert run.returncode == 0, run.stderr[-2000:]
+    return json.loads(run.stdout.strip().splitlines()[-1])
+
+
+@pytest.fixture(scope="module")
+def topo(llo_tally):
     from jax.experimental import topologies
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
@@ -131,3 +159,12 @@ def test_evolve3d_compiles(compiled_kernels):
         spec, 16, pallas_bitlife3d.BAYS_4555, True,
     )
     assert "tpu_custom_call" in text
+
+
+def test_fused_2d_grid_step_instruction_count(llo_tally):
+    """One grid step of the flagship plan (tile 256 stepped 8 generations)
+    stays aligned: it reads 36,992 VALU ops and 4,352 sublane rotates; a
+    shrinking window sliced at odd row offsets reads 58,588 and 14,704."""
+    assert (llo_tally["tile"], llo_tally["k"]) == (256, 8)
+    assert llo_tally["valu"] <= 40_000
+    assert llo_tally["vrot.slane"] <= 5_000
