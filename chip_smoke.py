@@ -331,12 +331,12 @@ def phase_serve(sz: Sizes, tmp: str) -> bool:
 def phase_3d(sz: Sizes, tmp: str, expect_kernel: bool) -> bool:
     """``gol_tpu.cli3d`` at 512³ with ``--engine pallas`` against
     ``--engine bitpack`` from the same soup (pattern 2)."""
-    from gol_tpu import cli3d
+    from gol_tpu import cli3d, volume
 
     boards, fields = {}, {}
     for engine in ("pallas", "bitpack"):
         seen = []
-        build = cli3d._build_evolver
+        build = volume._build_evolver
 
         def capture(*args, _build=build, _seen=seen, **kwargs):
             compiled, place = _build(*args, **kwargs)
@@ -347,7 +347,7 @@ def phase_3d(sz: Sizes, tmp: str, expect_kernel: bool) -> bool:
         argv = ["2", str(sz.volume), str(sz.volume_gens), "512", "0",
                 "--engine", engine, "--telemetry", tel, "--run-id", engine,
                 "--outdir", tmp]
-        with mock.patch.object(cli3d, "_build_evolver", capture):
+        with mock.patch.object(volume, "_build_evolver", capture):
             t0 = time.perf_counter()
             rc = cli3d.main(argv)
             wall = time.perf_counter() - t0

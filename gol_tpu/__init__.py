@@ -14,7 +14,8 @@ Layer map (mirrors SURVEY.md §1 of the reference):
   L1 CLI/driver            -> gol_tpu.cli, gol_tpu.cli3d (+ native/gol_driver.cpp)
   L2 distributed halo comm -> gol_tpu.parallel.halo (lax.ppermute rings);
                               multi-host via gol_tpu.parallel.multihost
-  L3 step orchestration    -> gol_tpu.runtime / parallel.{sharded,packed,
+  L3 step orchestration    -> gol_tpu.runtime, gol_tpu.volume (3-D) /
+                              parallel.{sharded,packed,
                               ruled,sharded3d} engines (+ guarded loop in
                               utils.guard)
   L4 device memory/runtime -> XLA HBM arrays + donation (no explicit mgmt)

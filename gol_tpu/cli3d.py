@@ -82,27 +82,6 @@ def init_volume(pattern: int, size: int) -> np.ndarray:
     raise ValueError(f"Pattern {pattern} has not been implemented")
 
 
-def _pallas3d_sharded_fits(mesh, size: int) -> bool:
-    """Whether the fused sharded 3-D kernel supports this mesh/geometry —
-    mirrors :func:`gol_tpu.parallel.sharded3d.compiled_evolve3d_pallas`'s
-    constraints, for ``auto`` resolution (an explicit ``--engine pallas``
-    raises the real errors instead)."""
-    from gol_tpu.ops import bitlife, pallas_bitlife3d
-    from gol_tpu.parallel.mesh import COLS, PLANES, ROWS
-
-    planes = mesh.shape.get(PLANES, 1)
-    rows = mesh.shape.get(ROWS, 1)
-    if (planes != 1 and rows != 1) or size % 128:
-        return False
-    band = size // (planes if rows == 1 else rows)
-    nw = size // mesh.shape.get(COLS, 1) // bitlife.BITS
-    return (
-        band >= 8
-        and nw >= 1
-        and pallas_bitlife3d.pick_tile3d_wt(band, nw, size, 8) is not None
-    )
-
-
 def _halo3d_block(mode: str, k: int, mesh, size: int, take: int) -> dict:
     """One 3-D chunk's schema-v8 ``halo`` block: the packed ring tier's
     exchange depth/count and band traffic (three ppermute phases — plane
@@ -131,144 +110,6 @@ def _halo3d_block(mode: str, k: int, mesh, size: int, take: int) -> dict:
         "band_bytes": chunk_bytes,
         "exchange_share": chunk_bytes / payload if payload else 0.0,
     }
-
-
-def _build_evolver(
-    engine: str, mesh, steps: int, rule, size: int, stats: bool = False,
-    shard_mode: str = "explicit", halo_depth: int = 1,
-):
-    """(compiled, place) for the chosen engine/mesh.
-
-    ``compiled`` is AOT-lowered from a ShapeDtypeStruct — like
-    ``GolRuntime.compile_evolvers``, compilation never executes a throwaway
-    evolution — and donates its input; ``place`` puts the host volume on
-    device(s) with the sharding the compiled program expects.
-
-    ``stats=True`` wraps the program in the in-graph volume reductions
-    (:func:`gol_tpu.telemetry.stats.wrap_evolver_3d`): the compiled
-    chunk returns ``(volume, stats)`` — population/births/deaths/changed
-    — with sharded volumes reduced at the global-array level (XLA
-    derives the collectives; the scalars replicate to every process).
-    The chunk-start volume stays live for the diff, so the wrapped form
-    forfeits the input donation (one extra volume of HBM).
-    """
-    import jax
-
-    def finish(fn, static, spec, place):
-        if stats:
-            from gol_tpu.telemetry import stats as stats_mod
-
-            wrapped = stats_mod.wrap_evolver_3d(fn, static)
-            return wrapped.lower(spec).compile(), place
-        return fn.lower(spec, *static).compile(), place
-
-    spec_shape = (size, size, size)
-    explicit_pallas = engine == "pallas"
-    engine = _resolve_engine3d(engine, mesh, size)
-    if mesh is not None:
-        from gol_tpu.parallel import sharded3d
-
-        packable = True
-        try:
-            sharded3d.validate_geometry3d_packed(spec_shape, mesh)
-        except ValueError:
-            packable = False
-        if engine in ("bitpack", "pallas") and not packable:
-            raise ValueError(
-                f"engine {engine!r} needs the x-shard width to pack into "
-                f"whole 32-cell words (size {size} over mesh "
-                f"{dict(mesh.shape)})"
-            )
-        if engine == "pallas":
-            # The fused word-tiled kernel per shard behind the two-phase
-            # ring exchange; an explicit --engine pallas surfaces its
-            # geometry constraints (H-unsharded mesh etc.) as clean
-            # errors — auto only resolves here when the geometry fits.
-            fn = sharded3d.compiled_evolve3d_pallas(mesh, steps, rule)
-        elif engine == "bitpack":
-            # The packed ring tier carries the temporal-blocking and
-            # chunk-form knobs: --halo-depth K ships a k-deep ghost
-            # shell per exchange, --shard-mode overlap/pipeline runs the
-            # depth-k interior/boundary split / cross-chunk double
-            # buffer (gol_tpu.parallel.halo; same forms as the 2-D
-            # driver, three ppermute phases instead of two).
-            fn = sharded3d.compiled_evolve3d_packed(
-                mesh, steps, rule, halo_depth, shard_mode
-            )
-        else:
-            sharded3d.validate_geometry3d(spec_shape, mesh)
-            fn = sharded3d.compiled_evolve3d(mesh, steps, rule)
-        sharding = sharded3d.volume_sharding(mesh)
-        spec = jax.ShapeDtypeStruct(spec_shape, np.uint8, sharding=sharding)
-        place = lambda v: jax.device_put(v, sharding)
-        return finish(fn, (), spec, place)
-
-    if engine == "pallas":
-        from gol_tpu.ops import pallas_bitlife3d
-
-        # strict only for an explicit --engine pallas: a benchmark must
-        # never be silently relabeled by the VMEM fallback; 'auto' keeps
-        # the silent substitution (it promises the fastest fit, not a
-        # specific program).
-        fn = pallas_bitlife3d.evolve3d
-        static = (steps, rule, explicit_pallas)
-    elif engine == "bitpack":
-        from gol_tpu.ops import bitlife3d
-
-        fn = bitlife3d.evolve3d_dense_io
-        static = (steps, rule)
-    else:
-        from gol_tpu.ops import life3d
-
-        fn = life3d.run3d
-        static = (steps, rule)
-    spec = jax.ShapeDtypeStruct(spec_shape, np.uint8)
-    return finish(fn, static, spec, jax.device_put)
-
-
-def _resolve_engine3d(engine: str, mesh, size: int) -> str:
-    """Map ``auto`` to the fastest tier this geometry supports (explicit
-    choices pass through and surface their own constraint errors).
-
-    The ONE auto policy — ``_build_evolver`` delegates here, so the
-    driver's checker-engine selection and the builder cannot drift.
-    ``auto`` never resolves to a Pallas configuration that would fall
-    back or raise: it promises the fastest *fit*, not a specific program.
-    """
-    import jax
-
-    if engine != "auto":
-        return engine
-    if mesh is not None:
-        from gol_tpu.parallel import sharded3d
-
-        packable = True
-        try:
-            sharded3d.validate_geometry3d_packed((size,) * 3, mesh)
-        except ValueError:
-            packable = False
-        if (
-            packable
-            and jax.default_backend() == "tpu"
-            and _pallas3d_sharded_fits(mesh, size)
-        ):
-            return "pallas"
-        return "bitpack" if packable else "dense"
-    if jax.default_backend() == "tpu" and size % 128 == 0:
-        # % 128 implies the % 32 word packing; still require a kernel
-        # window to actually fit scoped VMEM, else auto prefers the tier
-        # that runs as asked over one that silently substitutes.
-        from gol_tpu.ops import pallas_bitlife3d
-
-        nw = size // 32
-        if (
-            pallas_bitlife3d.pick_tile3d(size, nw, size)
-            or pallas_bitlife3d.pick_tile3d_wt(size, nw, size) is not None
-        ):
-            return "pallas"
-    if size % 32 == 0:
-        return "bitpack"
-    return "dense"
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
@@ -559,10 +400,17 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
         from gol_tpu.utils.timing import Stopwatch, force_ready
 
-        # Evolvers receive the raw choice (auto keeps its silent-fallback
-        # contract inside _build_evolver); the resolved name picks the
-        # redundant checker's counterpart engine.
-        resolved = _resolve_engine3d(ns.engine, mesh, size)
+        from gol_tpu.volume import VolumeRuntime
+
+        # One runtime resolves the engine and compiles and steps the
+        # chunk programs; the resolved name also picks the redundant
+        # checker's counterpart engine.
+        rt = VolumeRuntime(
+            size=size, rule=rule, engine=ns.engine, mesh=mesh,
+            shard_mode=ns.shard_mode, halo_depth=ns.halo_depth,
+            stats=ns.stats,
+        )
+        resolved = rt.resolved
 
         if ns.halo_depth < 1:
             raise ValueError(
@@ -756,37 +604,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             )
             schedule = chunk_schedule(iterations, interval)
             with sw.phase("compile"):
-                import time as time_mod
-
-                from gol_tpu.batch import cache as cache_mod
-
-                evolvers = {}
-                for take in set(schedule):
-                    probe = cache_mod.CompileCacheProbe()
-                    t0 = time_mod.perf_counter()
-                    evolvers[take] = _build_evolver(
-                        ns.engine, mesh, take, rule, size, stats=ns.stats,
-                        shard_mode=ns.shard_mode, halo_depth=ns.halo_depth,
-                    )
-                    if events is not None:
-                        # _build_evolver lowers + compiles in one step;
-                        # the record carries the combined duration (and,
-                        # schema v2, the compiled memory footprint).
-                        from gol_tpu.telemetry import stats as stats_mod
-
-                        cache_hit, cache_key = probe.resolve()
-                        events.compile_event(
-                            take,
-                            0.0,
-                            time_mod.perf_counter() - t0,
-                            memory=stats_mod.compiled_memory(
-                                evolvers[take][0]
-                            ),
-                            cache_hit=cache_hit,
-                            cache_key=cache_key,
-                        )
-                place = evolvers[schedule[0]][1]
-                board = placed if placed is not None else place(vol)
+                evolvers = rt.compile(schedule, events)
+                board = placed if placed is not None else rt.place(vol)
                 force_ready(board)
                 checker_evolvers = None
                 if ns.guard_redundant:
@@ -802,12 +621,12 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                             f"bit-packed — size {size} does not pack into "
                             "32-cell words"
                         )
+                    checker_rt = VolumeRuntime(
+                        size=size, rule=rule, engine=checker, mesh=mesh
+                    )
                     checker_evolvers = {
-                        take: (
-                            _build_evolver(checker, mesh, take, rule, size)[0],
-                            (),
-                        )
-                        for take in set(schedule)
+                        take: (c, ())
+                        for take, c in checker_rt.compile(schedule).items()
                     }
             if ns.guard_every > 0:
                 from gol_tpu.utils import guard as guard_mod
@@ -820,7 +639,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         board,
                         generation,
                         schedule,
-                        {t: (c, ()) for t, (c, _) in evolvers.items()},
+                        {t: (c, ()) for t, c in evolvers.items()},
                         checker_evolvers,
                         guard_mod.GuardConfig(
                             check_every=ns.guard_every,
@@ -847,35 +666,25 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                 )
                 with resilience.preemption_guard(), maybe_profile(
                     ns.profile
-                ), telemetry_mod.trace_annotation(
-                    "gol3d.run.evolve"
-                ):
+                ), telemetry_mod.trace_annotation("gol.run.evolve", dims=3):
                     for i, take in enumerate(schedule):
-                        compiled, _ = evolvers[take]
-                        dev_stats = None
-                        with telemetry_mod.step_annotation("gol.chunk", i):
-                            with sw.phase("total"):
-                                t0 = time_mod.perf_counter()
-                                out3 = compiled(board)
-                                t1 = time_mod.perf_counter()
-                                if ns.stats:
-                                    board, dev_stats = out3
-                                else:
-                                    board = out3
-                                force_ready(board)
-                                dt = time_mod.perf_counter() - t0
-                        if plan_on:
-                            # Fault-plane SDC injection (board.bitflip,
-                            # plane/row/col): host-side functional cell
-                            # update — the un-audited path takes the
-                            # corruption silently by design.
-                            board = faults_mod.apply_board_faults(
-                                board, generation + take
+                        with sw.phase("total"):
+                            board, dev_stats, dispatch_s, dt = rt.chunk(
+                                i, take, board
                             )
-                        generation += take
+                        # Fault-plane SDC injection (board.bitflip,
+                        # plane/row/col) rides gol.advance: a host-side
+                        # functional cell update — the un-audited path
+                        # takes the corruption silently by design.
+                        board, generation = rt.advance(
+                            board, generation, take
+                        )
                         if events is not None:
-                            sc.add("dispatch", t1 - t0)
-                            sc.add("ready", dt - (t1 - t0))
+                            # dispatch = enqueue until the async call
+                            # returns; ready = the fence.  Timed once,
+                            # by the runtime's spans.
+                            sc.add("dispatch", dispatch_s)
+                            sc.add("ready", dt - dispatch_s)
                             spans = sc.take()
                             extra3 = {}
                             if mesh is not None and resolved == "bitpack":
@@ -913,10 +722,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         ]:
                             with telemetry_mod.trace_annotation(
                                 "gol.checkpoint.save"
-                            ), sw.phase("checkpoint"):
-                                t0 = time_mod.perf_counter()
+                            ) as save, sw.phase("checkpoint"):
                                 save_snapshot(board, generation)
-                                dt = time_mod.perf_counter() - t0
+                            dt = save.seconds
                             if sc is not None:
                                 sc.add("checkpoint", dt)
                             if events is not None:

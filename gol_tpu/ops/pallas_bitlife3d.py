@@ -34,12 +34,13 @@ kernels are VPU-bound, so duplicated ghost compute decides); the
 word-tiled kernel's window DMA is double-buffered across plane chunks,
 the plane kernel's measured better serial (see :func:`_kernel`).
 
-Measured on one v5e chip (Bays 4555, ×128-step runs so the
-per-invocation overhead doesn't dilute the rates; earlier round-2 notes
-used ×32 and under-reported): **7.3e10 cell-updates/s at 512³** via the
-plane kernel (XLA packed: 5.9e10), **1.78e11 at 768³** (wt kernel (48,
-4), beating both the plane kernel's 1.61e11 and XLA's 6.9e10 — 2.6×),
-and **2.35e11 at 1024³** (wt (32, 4); XLA packed: 6.6e10 — 3.5×).
+Older rounds' timings (r2–r4, ``BASELINE.md``; kernels since changed,
+not the benchmark's): 7.3e10 cell-updates/s at 512³ via the plane kernel
+(XLA packed: 5.9e10), 1.78e11 at 768³ (wt (48, 4)), 2.35e11 at 1024³ (wt
+(32, 4)) and, measured together at ×256, 4.8e11 for roll(32/64)
+against wt's 3.3e11.  The benchmark's cell ``life3d-1024.soup`` times the kernel
+``auto`` picks at 1024³ (roll, tile 64, k 8) through ``VolumeRuntime``;
+``PERF.md`` holds its numbers.
 """
 
 from __future__ import annotations
@@ -53,6 +54,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from gol_tpu import telemetry
 from gol_tpu.ops import bitlife, bitlife3d, pallas_common
 from gol_tpu.ops.life3d import BAYS_4555, Rule3D
 from gol_tpu.ops.pallas_bitlife import _pick_block
@@ -835,6 +837,14 @@ def pick_tile3d(depth: int, nw: int, h: int, pad: int = _ALIGN) -> int:
     return 0
 
 
+def _record_kernel(kernel: str, tile=None, k=None) -> None:
+    """Count the kernel ``evolve3d`` picked (``gol3d.kernel.<kernel>``)
+    and put it, its tile and its k on the open span: at trace time, so
+    once per compiled program."""
+    telemetry.count(f"gol3d.kernel.{kernel}")
+    telemetry.annotate(kernel=kernel, tile=tile, k=k)
+
+
 @functools.partial(jax.jit, static_argnums=(1, 2, 3), donate_argnums=(0,))
 def evolve3d(
     vol: jax.Array, steps: int, rule: Rule3D = BAYS_4555,
@@ -889,6 +899,7 @@ def evolve3d(
                 f"{(d, h, w)} in scoped VMEM (neither whole, rolling, nor "
                 "word-tiled plane windows); use engine 'auto' or 'bitpack'"
             )
+        _record_kernel("xla_fallback")
         return bitlife3d.unpack3d(
             bitlife3d.run3d_packed(bitlife3d.pack3d(vol), steps, rule)
         )
@@ -899,6 +910,7 @@ def evolve3d(
             bitlife3d.pack3d(vol), jnp.int32
         ).transpose(2, 0, 1)
         k = _pick_block(steps, tile_d, _BLOCK, _ALIGN)
+        _record_kernel("wt", [tile_d, tile_w], k)
         full, rem = divmod(steps, k)
         packed_w = lax.fori_loop(
             0,
@@ -927,6 +939,7 @@ def evolve3d(
         bitlife3d.pack3d(vol), jnp.int32
     ).transpose(0, 2, 1)
     k = _pick_block(steps, t, _BLOCK, _ALIGN)
+    _record_kernel(choice, t, k)
     full, rem = divmod(steps, k)
     packed_t = lax.fori_loop(
         0,

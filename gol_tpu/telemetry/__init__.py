@@ -1048,6 +1048,15 @@ def count(name: str, n: int = 1, seconds: float = 0.0) -> None:
         _add(_counters, name, n, seconds)
 
 
+def annotate(**attrs) -> None:
+    """Add ``attrs`` to this thread's innermost open span, if any: for
+    what code deep inside a span decides, such as the kernel a traced
+    program picked."""
+    stack = _open_spans.stack
+    if stack:
+        stack[-1].attrs.update(attrs)
+
+
 def _on_jax_duration(event: str, duration: float, **_) -> None:
     if event.startswith(COMPILE_EVENT_PREFIXES):
         count(event, 1, duration)

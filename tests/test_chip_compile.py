@@ -1,7 +1,7 @@
 """The chip's compiler, without the chip: AOT compiles for a described v5e.
 
-The kernels ``chip_smoke.py`` reaches, at the widths it runs them, are
-compiled for a ``v5e:2x2`` topology that is described, not attached
+The kernels ``chip_smoke.py`` and the benchmark reach, at the sizes
+they run them, are compiled for a ``v5e:2x2`` topology that is described, not attached
 (``on-chip-measurement`` guide, section 2).  What Mosaic refuses here —
 unaligned slices, too much VMEM, a program that does not fit — would
 otherwise cost chip time to find.  Each test asserts the program holds a
@@ -37,6 +37,7 @@ from jax.sharding import SingleDeviceSharding
 
 BOARD = 16384  # BASELINE config 3, the smoke's CLI / soup / ring board
 VOLUME = 512  # config 5 cut to one chip, the smoke's 3-D phase
+VOLUME_CELL = 1024  # config 5 whole, the benchmark's life3d-1024 job
 
 
 TALLY = pathlib.Path(__file__).resolve().parent.parent / "scripts" / (
@@ -146,17 +147,18 @@ def test_packed_pallas_ring_compiles(compiled_kernels, mesh_shape, form):
         assert "collective-permute" in text
 
 
-def test_evolve3d_compiles(compiled_kernels):
+@pytest.mark.parametrize("volume,gens", [(VOLUME, 16), (VOLUME_CELL, 64)])
+def test_evolve3d_compiles(compiled_kernels, volume, gens):
     from gol_tpu.ops import pallas_bitlife3d
 
     spec = jax.ShapeDtypeStruct(
-        (VOLUME,) * 3, jnp.uint8,
+        (volume,) * 3, jnp.uint8,
         sharding=SingleDeviceSharding(compiled_kernels.devices[0]),
     )
     text = _text(
         jax.jit(pallas_bitlife3d.evolve3d.__wrapped__,
                 static_argnums=(1, 2, 3)),
-        spec, 16, pallas_bitlife3d.BAYS_4555, True,
+        spec, gens, pallas_bitlife3d.BAYS_4555, True,
     )
     assert "tpu_custom_call" in text
 

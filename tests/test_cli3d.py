@@ -7,7 +7,7 @@ import pytest
 
 import jax
 
-from gol_tpu import cli3d
+from gol_tpu import cli3d, volume
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -355,7 +355,7 @@ def test_guard3d_fault_drill():
 
     size, rule = 32, cli3d.parse_rule3d("bays4555")
     vol = cli3d.init_volume(2, size)
-    compiled, place = cli3d._build_evolver("bitpack", None, 4, rule, size)
+    compiled, place = volume._build_evolver("bitpack", None, 4, rule, size)
     evolvers = {4: (compiled, ())}
     fired = []
 
@@ -387,10 +387,10 @@ def test_guard3d_redundant_catches_inrange_flip():
     size, rule = 32, cli3d.parse_rule3d("bays4555")
     vol = cli3d.init_volume(2, size)
     evolvers = {
-        4: (cli3d._build_evolver("bitpack", None, 4, rule, size)[0], ())
+        4: (volume._build_evolver("bitpack", None, 4, rule, size)[0], ())
     }
     checkers = {
-        4: (cli3d._build_evolver("dense", None, 4, rule, size)[0], ())
+        4: (volume._build_evolver("dense", None, 4, rule, size)[0], ())
     }
     fired = []
 
