@@ -50,6 +50,7 @@ from typing import FrozenSet
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -837,12 +838,76 @@ def pick_tile3d(depth: int, nw: int, h: int, pad: int = _ALIGN) -> int:
     return 0
 
 
-def _record_kernel(kernel: str, tile=None, k=None) -> None:
+def _contraction_dtype():
+    """The pack contraction's operand dtype: bf16 where the kernels
+    compile (the MXU multiplies bf16), f32 where they are interpreted
+    (the CPU's dot has no bf16 x bf16 -> f32).  Both hold its operands,
+    0/1 and powers of two up to 2^15, exactly."""
+    return jnp.float32 if pallas_common.interpret() else jnp.bfloat16
+
+
+def pack_transposed(vol: jax.Array) -> jax.Array:
+    """uint8[D, H, W] 0/1 volume -> int32[D, W//32, H], the kernels' layout.
+
+    Bit-identical to ``bitcast(bitlife3d.pack3d(vol), int32)
+    .transpose(0, 2, 1)``: bit j of word k is column 32k+j.  One
+    contraction over W packs and transposes at once, on the MXU:
+    ``half[d, c, h] = sum_w vol[d, h, w] * E[w, c]`` with
+    ``E[w, c] = 2^(w % 16)`` where ``c`` is ``w // 32`` for the word's
+    low 16 columns and ``nw + w // 32`` for its high ones.  Every product
+    is exact and every sum is at most 65535, well inside the f32
+    accumulator, so the halves are exact; a word is then
+    ``lo | hi << 16`` over two sublane-aligned row blocks.  The uint8
+    volume is read once, by the contraction, and never relaid out.
+    """
+    _, _, w = vol.shape
+    nw = bitlife.packed_width(w)
+    col = np.arange(w)
+    weights = np.zeros((w, 2 * nw), np.float32)
+    weights[col, (col // 16) % 2 * nw + col // 32] = 2.0 ** (col % 16)
+    dt = _contraction_dtype()
+    halves = jnp.einsum(
+        "dhw,wc->dch", vol.astype(dt), jnp.asarray(weights, dt),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)
+    return halves[:, :nw] | (halves[:, nw:] << 16)
+
+
+def unpack_transposed(packed_t: jax.Array) -> jax.Array:
+    """Inverse of :func:`pack_transposed`: int32[D, nw, H] -> uint8[D, H, 32 nw].
+
+    The words split into bytes along the sublane axis (row block ``b``
+    holds byte ``b`` of every word), and one int8 contraction
+    ``[H, 4 nw] . [4 nw, W]`` per plane puts each byte, read as a signed
+    int8, under its 8 columns.  A cell is bit ``w % 8`` of that int32:
+    the low byte of a two's complement value keeps its bits under an
+    arithmetic shift.  The uint8 volume is written once, by the
+    contraction's epilogue.
+    """
+    _, nw, _ = packed_t.shape
+    w = nw * bitlife.BITS
+    col = np.arange(w)
+    byte = col // 8
+    spread = np.zeros((4 * nw, w), np.int8)
+    spread[byte % 4 * nw + byte // 4, col] = 1
+    by = jnp.concatenate(
+        [packed_t >> (8 * b) for b in range(4)], axis=1
+    ).astype(jnp.int8)
+    v = jnp.einsum(
+        "dch,cw->dhw", by, jnp.asarray(spread),
+        preferred_element_type=jnp.int32,
+    )
+    return ((v >> jnp.asarray(col % 8, jnp.int32)) & 1).astype(jnp.uint8)
+
+
+def _record_kernel(kernel: str, tile=None, k=None, convert="mxu") -> None:
     """Count the kernel ``evolve3d`` picked (``gol3d.kernel.<kernel>``)
-    and put it, its tile and its k on the open span: at trace time, so
-    once per compiled program."""
+    and the conversion around it (``gol3d.convert.<convert>``), and put
+    them, the tile and k on the open span: at trace time, so once per
+    compiled program."""
     telemetry.count(f"gol3d.kernel.{kernel}")
-    telemetry.annotate(kernel=kernel, tile=tile, k=k)
+    telemetry.count(f"gol3d.convert.{convert}")
+    telemetry.annotate(kernel=kernel, tile=tile, k=k, convert=convert)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3), donate_argnums=(0,))
@@ -850,10 +915,11 @@ def evolve3d(
     vol: jax.Array, steps: int, rule: Rule3D = BAYS_4555,
     strict: bool = False,
 ) -> jax.Array:
-    """Dense uint8 in/out: pack, transpose, fused-evolve, restore.
+    """Dense uint8 in/out: pack and transpose, fused-evolve, restore.
 
-    The transpose pair costs two XLA copies total — amortized over the
-    whole generation loop, which runs as temporally-blocked Pallas
+    :func:`pack_transposed` and :func:`unpack_transposed` convert between
+    the uint8 volume and the kernels' layout, one contraction each way;
+    between them the generation loop runs as temporally-blocked Pallas
     launches (full k-blocks then one remainder).
 
     ``strict=True`` raises instead of taking the XLA fallback when no
@@ -899,16 +965,14 @@ def evolve3d(
                 f"{(d, h, w)} in scoped VMEM (neither whole, rolling, nor "
                 "word-tiled plane windows); use engine 'auto' or 'bitpack'"
             )
-        _record_kernel("xla_fallback")
+        _record_kernel("xla_fallback", convert="xla")
         return bitlife3d.unpack3d(
             bitlife3d.run3d_packed(bitlife3d.pack3d(vol), steps, rule)
         )
     choice = min(cands)[2]
     if choice == "wt":
         tile_d, tile_w = wt
-        packed_w = lax.bitcast_convert_type(
-            bitlife3d.pack3d(vol), jnp.int32
-        ).transpose(2, 0, 1)
+        packed_w = pack_transposed(vol).transpose(1, 0, 2)
         k = _pick_block(steps, tile_d, _BLOCK, _ALIGN)
         _record_kernel("wt", [tile_d, tile_w], k)
         full, rem = divmod(steps, k)
@@ -924,20 +988,14 @@ def evolve3d(
             packed_w = multi_step_pallas_packed3d_wt(
                 packed_w, tile_d, tile_w, rem, rule
             )
-        return bitlife3d.unpack3d(
-            lax.bitcast_convert_type(
-                packed_w.transpose(1, 2, 0), jnp.uint32
-            )
-        )
+        return unpack_transposed(packed_w.transpose(1, 0, 2))
     step_fn = (
         multi_step_pallas_packed3d
         if choice == "plane"
         else multi_step_pallas_packed3d_roll
     )
     t = tile if choice == "plane" else roll
-    packed_t = lax.bitcast_convert_type(
-        bitlife3d.pack3d(vol), jnp.int32
-    ).transpose(0, 2, 1)
+    packed_t = pack_transposed(vol)
     k = _pick_block(steps, t, _BLOCK, _ALIGN)
     _record_kernel(choice, t, k)
     full, rem = divmod(steps, k)
@@ -949,6 +1007,4 @@ def evolve3d(
     )
     if rem:
         packed_t = step_fn(packed_t, t, rem, rule)
-    return bitlife3d.unpack3d(
-        lax.bitcast_convert_type(packed_t.transpose(0, 2, 1), jnp.uint32)
-    )
+    return unpack_transposed(packed_t)

@@ -24,6 +24,7 @@ from __future__ import annotations
 import json
 import os
 import pathlib
+import re
 import subprocess
 import sys
 
@@ -98,6 +99,59 @@ def _text(fn, *specs) -> str:
     return fn.lower(*specs).compile().as_text()
 
 
+@pytest.fixture(scope="module")
+def evolve3d_program(compiled_kernels):
+    """``evolve3d`` compiled for one chip of the described topology,
+    ``strict`` as an explicit ``--engine pallas`` runs it; each size
+    compiled once."""
+    from gol_tpu.ops import pallas_bitlife3d
+
+    programs = {}
+
+    def compile_(volume, gens):
+        if (volume, gens) not in programs:
+            spec = jax.ShapeDtypeStruct(
+                (volume,) * 3, jnp.uint8,
+                sharding=SingleDeviceSharding(compiled_kernels.devices[0]),
+            )
+            programs[volume, gens] = jax.jit(
+                pallas_bitlife3d.evolve3d.__wrapped__,
+                static_argnums=(1, 2, 3),
+            ).lower(spec, gens, pallas_bitlife3d.BAYS_4555, True).compile()
+        return programs[volume, gens]
+
+    return compile_
+
+
+_HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
+              "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+              "f64": 8}
+_HLO_SHAPE = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\]")
+_HLO_OP = re.compile(r"\s*(?:ROOT )?%?(\S+) = (.*?) ([\w-]+)\(")
+# Ops that name bytes another op wrote, and move none themselves.
+_NO_TRAFFIC = {"parameter", "bitcast", "get-tuple-element", "tuple",
+               "constant"}
+
+
+def _entry_writes(text: str):
+    """(name, op, result bytes) of each op of the entry computation that
+    writes its result; the while body and fusions are computations of
+    their own, so their ops are not listed."""
+    body = text[text.index("\nENTRY "):]
+    body = body[body.index("{\n") + 2:body.index("\n}")]
+    for line in body.splitlines():
+        m = _HLO_OP.match(line)
+        if not m or m.group(3) in _NO_TRAFFIC:
+            continue
+        size = 0
+        for dtype, dims in _HLO_SHAPE.findall(m.group(2)):
+            if dtype in _HLO_BYTES:
+                size += _HLO_BYTES[dtype] * int(
+                    np.prod([int(d) for d in dims.split(",") if d])
+                )
+        yield m.group(1), m.group(3), size
+
+
 def test_fused_2d_kernel_compiles(compiled_kernels):
     from gol_tpu.ops import pallas_bitlife
 
@@ -148,19 +202,22 @@ def test_packed_pallas_ring_compiles(compiled_kernels, mesh_shape, form):
 
 
 @pytest.mark.parametrize("volume,gens", [(VOLUME, 16), (VOLUME_CELL, 64)])
-def test_evolve3d_compiles(compiled_kernels, volume, gens):
-    from gol_tpu.ops import pallas_bitlife3d
-
-    spec = jax.ShapeDtypeStruct(
-        (volume,) * 3, jnp.uint8,
-        sharding=SingleDeviceSharding(compiled_kernels.devices[0]),
-    )
-    text = _text(
-        jax.jit(pallas_bitlife3d.evolve3d.__wrapped__,
-                static_argnums=(1, 2, 3)),
-        spec, gens, pallas_bitlife3d.BAYS_4555, True,
-    )
+def test_evolve3d_compiles(evolve3d_program, volume, gens):
+    text = evolve3d_program(volume, gens).as_text()
     assert "tpu_custom_call" in text
+
+
+def test_evolve3d_conversion_passes(evolve3d_program):
+    """The benchmark's 1024³ job converts its volume in two full-volume
+    passes outside the generation loop: the pack contraction's 256 MiB
+    of 16-bit halves, and the unpack contraction that writes the 1 GiB
+    uint8 volume.  XLA's packing of the same layout wrote the uint8
+    volume or a relayout of it six times, with 2 GiB of temporaries."""
+    compiled = evolve3d_program(VOLUME_CELL, 64)
+    big = [(name, op, size) for name, op, size
+           in _entry_writes(compiled.as_text()) if size >= 256 << 20]
+    assert len(big) == 2, big
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 << 30
 
 
 def test_fused_2d_grid_step_instruction_count(llo_tally):

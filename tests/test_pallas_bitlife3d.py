@@ -339,3 +339,68 @@ def test_score_dispatch_prefers_lower_recompute(monkeypatch):
     ref = np.asarray(bitlife3d.evolve3d_dense_io(jnp.asarray(vol), 3))
     np.testing.assert_array_equal(got, ref)
     assert calls  # the word-tiled kernel won the dispatch
+
+
+# -- the conversion between the uint8 volume and the kernels' layout ---------
+
+
+def _volume(kind, shape):
+    d, h, w = shape
+    if kind == "ones":
+        return np.ones(shape, np.uint8)
+    if kind == "zeros":
+        return np.zeros(shape, np.uint8)
+    vol = _rand_vol(d, h, w, seed=d + h + w)
+    if kind == "sign_bit":  # column 32k+31 live: every word is negative
+        vol[..., 31::32] = 1
+    return vol
+
+
+@pytest.mark.parametrize("kind", ["random", "ones", "zeros", "sign_bit"])
+@pytest.mark.parametrize(
+    "shape", [(8, 16, 64), (16, 8, 128), (24, 40, 96), (8, 8, 32)]
+)
+def test_pack_transposed_is_pack3d_transposed(kind, shape):
+    """Bit j of word k is column 32k+j, as in ``pack3d``, and the pair
+    round-trips."""
+    vol = jnp.asarray(_volume(kind, shape))
+    want = jax.lax.bitcast_convert_type(
+        bitlife3d.pack3d(vol), jnp.int32
+    ).transpose(0, 2, 1)
+    got = pallas_bitlife3d.pack_transposed(vol)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    back = pallas_bitlife3d.unpack_transposed(got)
+    assert back.dtype == jnp.uint8
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(vol))
+
+
+def _lowered_in_span(name, vol_shape, steps):
+    from gol_tpu import telemetry
+
+    spec = jax.ShapeDtypeStruct(vol_shape, jnp.uint8)
+    with telemetry.trace_annotation(name):
+        pallas_bitlife3d.evolve3d.lower(spec, steps)
+    return [r for r in telemetry.span_log()["records"] if r["name"] == name][-1]
+
+
+def test_conversion_is_counted_and_annotated():
+    """``evolve3d`` counts the conversion it traced and names it on the
+    open span, beside the kernel."""
+    rec = _lowered_in_span("test.convert.mxu", (8, 8, 160), 2)
+    assert rec["attrs"]["convert"] == "mxu"
+    assert rec["counts"]["gol3d.convert.mxu"][0] == 1
+
+
+def test_xla_fallback_counts_the_xla_conversion(monkeypatch):
+    monkeypatch.setattr(pallas_bitlife3d, "pick_tile3d", lambda *a, **k: 0)
+    monkeypatch.setattr(
+        pallas_bitlife3d, "pick_tile3d_wt", lambda *a, **k: None
+    )
+    monkeypatch.setattr(
+        pallas_bitlife3d, "pick_tile3d_roll", lambda *a, **k: 0
+    )
+    rec = _lowered_in_span("test.convert.xla", (8, 8, 224), 2)
+    assert rec["attrs"]["convert"] == "xla"
+    assert rec["attrs"]["kernel"] == "xla_fallback"
+    assert rec["counts"]["gol3d.convert.xla"][0] == 1
