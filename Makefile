@@ -7,10 +7,9 @@
 # scripts/check.sh).
 
 .PHONY: lint verify lockcheck test check telemetry-smoke stats-smoke \
-	resilience-drill batch-smoke batchbench sparse-smoke sparsebench \
-	obs-smoke ledger-check reshard-smoke halo-smoke halobench-sweep \
-	chaos-smoke chaos-matrix serve-smoke servebench elastic-smoke \
-	trace-smoke postmortem-smoke fleet-smoke ooc-smoke oocbench
+	resilience-drill batch-smoke sparse-smoke obs-smoke reshard-smoke \
+	halo-smoke halobench-sweep chaos-smoke chaos-matrix serve-smoke \
+	elastic-smoke trace-smoke postmortem-smoke fleet-smoke ooc-smoke
 
 lint:
 	bash scripts/lint.sh
@@ -58,33 +57,16 @@ resilience-drill:
 batch-smoke:
 	JAX_PLATFORMS=cpu python scripts/batch_smoke.py
 
-# Per-world-throughput-vs-B amortization curve -> BATCH_r{N}.json
-# (CPU: curve shape; the TPU headline is --size 256 --iters 1024).
-batchbench:
-	python benchmarks/batchbench.py --round 6
-
 # Activity-gated smoke (docs/SPARSE.md): glider-gun run bit-equal to
 # the dense bitpack tier while skipping most tile-generations.
 sparse-smoke:
 	JAX_PLATFORMS=cpu python scripts/sparse_smoke.py
 
-# Dense-vs-gated speedup curve over live-cell fraction ->
-# SPARSE_r{N}.json (CPU: curve shape; the TPU headline is
-# --size 65536 --iters 256).
-sparsebench:
-	python benchmarks/sparsebench.py --tile 128 --capacity 0.125 --round 7
-
 # Continuous-observability smoke (docs/OBSERVABILITY.md): live run with
 # --metrics-port scraped mid-run + reconciled with the JSONL, v6 spans
-# on every chunk, summarize's span table, and the ledger gate.
+# on every chunk, and summarize's span table.
 obs-smoke:
 	JAX_PLATFORMS=cpu python scripts/obs_smoke.py
-
-# The cross-run perf regression gate alone: newest record per config
-# fingerprint vs the best on the committed chip-round history.
-ledger-check:
-	JAX_PLATFORMS=cpu python -m gol_tpu.telemetry ledger check \
-	    --ledger tests/data/ledger/history.jsonl
 
 # Elastic-mesh smoke (docs/RESILIENCE.md): a 2-D-block sharded snapshot
 # resumed on a 1-D ring bit-equal to a straight run, with a
@@ -97,8 +79,8 @@ reshard-smoke:
 halo-smoke:
 	JAX_PLATFORMS=cpu python scripts/halo_smoke.py
 
-# The k-vs-MFU depth sweep (HALO_r07.json's command; curve shape only
-# on CPU — the TPU headline geometry is pinned in the artifact's note).
+# The k-vs-MFU depth sweep (curve shape only on CPU — the TPU headline
+# geometry is pinned in the output's note).
 halobench-sweep:
 	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 	    python -m gol_tpu.utils.halobench 1024 16 1d:4 \
@@ -157,24 +139,12 @@ postmortem-smoke:
 fleet-smoke:
 	JAX_PLATFORMS=cpu python scripts/fleet_smoke.py
 
-# Open-loop serving load curve -> SERVE_r{N}.json (CPU: admission /
-# queue dynamics; the TPU headline command is pinned in the note).
-servebench:
-	JAX_PLATFORMS=cpu python benchmarks/servebench.py \
-	    --rates 4,16,64,400,2000 --requests 48 --generations 24 --round 1
-
 # Out-of-core streaming smoke (docs/STREAMING.md): a Gosper gun on a
 # board >=4x the rotation's device footprint, streamed through
 # --engine ooc — bit-equal to the in-core bitpack tier, dead bands
 # skipped, v15 ooc blocks with measured overlap_fraction on every chunk.
 ooc-smoke:
 	JAX_PLATFORMS=cpu python scripts/ooc_smoke.py
-
-# Streaming-efficiency curve over board/budget ratios -> OOC_r{N}.json
-# (CPU: curve shape; the TPU headline is --height 1048576
-# --width 1048576 --budget-mb 4096 --iters 64).
-oocbench:
-	python benchmarks/oocbench.py --round 1
 
 check:
 	bash scripts/check.sh

@@ -192,7 +192,9 @@ def phase_native() -> bool:
     )
     from gol_tpu.utils import native
 
-    return report("native", proc.returncode == 0 and native.available(),
+    # reload(): an earlier probe in this process (a rank-file write, say)
+    # memoized the library's absence before this build.
+    return report("native", proc.returncode == 0 and native.reload(),
                   wall_s=time.perf_counter() - t0,
                   error=proc.stderr[-400:] if proc.returncode else None)
 

@@ -45,6 +45,8 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from gol_tpu.parallel import modes as modes_mod
+
 USAGE = (
     "GOL requires 5 arguments: pattern number, sq size of the world and the "
     "number of itterations, threads per block and output-on-off e.g. "
@@ -69,13 +71,10 @@ def parse_args(argv: Sequence[str]) -> Optional[argparse.Namespace]:
     ext = argparse.ArgumentParser(prog="gol", add_help=True)
     ext.add_argument("positionals", nargs="*", metavar="ARG")
     ext.add_argument("--ranks", type=int, default=1)
-    ext.add_argument("--halo", choices=["fresh", "stale_t0"], default="fresh")
+    ext.add_argument("--halo", choices=modes_mod.HALO_MODES, default="fresh")
     ext.add_argument(
         "--engine",
-        choices=[
-            "auto", "dense", "bitpack", "pallas", "pallas_bitpack",
-            "activity", "ooc",
-        ],
+        choices=modes_mod.ENGINES,
         default="auto",
     )
     # Activity-gated tier knobs (docs/SPARSE.md): mask tile edge (0 =
@@ -101,7 +100,7 @@ def parse_args(argv: Sequence[str]) -> Optional[argparse.Namespace]:
     # while chunk N's interior computes — docs/DESIGN.md).
     ext.add_argument(
         "--shard-mode",
-        choices=["explicit", "overlap", "auto", "pipeline"],
+        choices=modes_mod.SHARD_MODES,
         default="explicit",
     )
     ext.add_argument("--halo-depth", type=int, default=1, metavar="K")
@@ -497,13 +496,14 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     "--batch is single-process (its mesh spans local "
                     "devices); drop the multi-host flags"
                 )
-            if ns.halo != "fresh":
-                raise ValueError("--batch runs fresh halos only")
-            if ns.rule:
-                raise ValueError(
-                    "--batch runs the B3/S23 fast paths; --rule is a "
-                    "single-world feature"
-                )
+            modes_mod.check_run(
+                ns.engine,
+                ns.engine,
+                halo_mode=ns.halo,
+                custom_rule=bool(ns.rule),
+                mesh=None if ns.mesh == "none" else ns.mesh,
+                batch=True,
+            )
             if ns.stats:
                 raise ValueError(
                     "--stats is a single-world observer; drop it in "
@@ -513,24 +513,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             if ns.profile:
                 raise ValueError(
                     "--profile applies to single-world runs; drop --batch"
-                )
-            if ns.mesh == "2d":
-                raise ValueError(
-                    "--batch shards the world axis (a 1-D ring); use "
-                    "--mesh 1d or --mesh none"
-                )
-            if ns.engine == "ooc":
-                raise ValueError(
-                    "--batch evolves many in-core worlds in one compiled "
-                    "program; engine 'ooc' streams one bigger-than-device "
-                    "world through the chip — run it unbatched, or pick a "
-                    "batched engine ('auto', 'dense', 'bitpack', "
-                    "'pallas_bitpack')"
-                )
-            if ns.engine in ("pallas", "activity"):
-                raise ValueError(
-                    f"engine {ns.engine!r} has no batched tier; "
-                    "use 'auto'/'dense'/'bitpack'/'pallas_bitpack'"
                 )
             sizes_text = ns.batch_sizes or str(ns.world_size)
             batch_sizes = [atoi(s) for s in sizes_text.split(",") if s]

@@ -30,8 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from gol_tpu.ops import stencil
-
-HALO_MODES = ("fresh", "stale_t0")
+from gol_tpu.parallel.modes import HALO_MODES
 
 
 def split_ranks(global_board: jax.Array, num_ranks: int) -> jax.Array:

@@ -207,7 +207,7 @@ def compiled_evolve_packed_pallas(
     VMEM budget set the real cap per geometry: wide boards cap at 256
     (nw=512's budget), narrow lane-folded shards reach 1024.  Earlier
     rounds defaulted the hint to 128 off wall-clock sweeps; r5's
-    two-point overhead fits (benchmarks/exp_tile_fit.py, BASELINE.md r5)
+    two-point overhead fits (BASELINE.md r5)
     showed those walls were per-invocation-overhead artifacts — device
     side, the folded 16384×1024 pod shard runs 2.01e12 cell-updates/s at tile 1024
     vs 1.49e12 at tile 128 (+35%), and the full 16384² board gains ~4%

@@ -58,8 +58,7 @@ from gol_tpu.parallel.halo import (
 )
 from gol_tpu.parallel.mesh import COLS, ROWS, board_sharding, validate_geometry
 from gol_tpu.parallel.mesh import place_private as mesh_place_private
-
-MODES = ("explicit", "overlap", "auto", "pipeline")
+from gol_tpu.parallel.modes import SHARD_MODES as MODES
 
 
 def exchange_row_halos(block: jax.Array, num_rows: int):

@@ -29,14 +29,12 @@ from gol_tpu.models import patterns
 from gol_tpu.models.state import Geometry, GolState
 from gol_tpu.parallel import engine as engine_mod
 from gol_tpu.parallel import mesh as mesh_mod
+from gol_tpu.parallel import modes as modes_mod
 from gol_tpu.parallel import packed as packed_mod
 from gol_tpu.parallel import sharded as sharded_mod
 from gol_tpu.utils import checkpoint as ckpt_mod
 from gol_tpu.utils.timing import RunReport, Stopwatch, force_ready, maybe_profile
 
-ENGINES = (
-    "auto", "dense", "bitpack", "pallas", "pallas_bitpack", "activity", "ooc"
-)
 MESH_CHOICES = ("none", "1d", "2d")
 
 
@@ -217,92 +215,39 @@ class GolRuntime:
 
     def _configure(self) -> None:
         """Validate the knobs, resolve the engine and check the mesh."""
-        if self.engine not in ENGINES:
-            raise ValueError(f"unknown engine {self.engine!r}; expected {ENGINES}")
-        if self.halo_mode not in engine_mod.HALO_MODES:
-            raise ValueError(f"unknown halo_mode {self.halo_mode!r}")
-        if self.shard_mode not in sharded_mod.MODES:
-            raise ValueError(
-                f"unknown shard_mode {self.shard_mode!r}; expected "
-                f"{sharded_mod.MODES}"
-            )
         if self.checkpoint_every and not self.checkpoint_dir:
             self.checkpoint_dir = "checkpoints"
-        if self.halo_depth < 1:
-            raise ValueError(f"halo_depth must be >= 1, got {self.halo_depth}")
         self._rule = None
         if self.rule is not None:
             from gol_tpu.ops import rules as rules_mod
 
             parsed = rules_mod.parse_rulestring(self.rule)
             if parsed != rules_mod.CONWAY:
-                if self.halo_mode != "fresh":
-                    raise ValueError(
-                        "custom rules have no stale_t0 reference-compat mode "
-                        "(the reference only implements B3/S23)"
-                    )
-                if self.engine == "pallas":
-                    raise ValueError(
-                        "engine 'pallas' (dense kernel) is hard-wired to "
-                        "B3/S23; use 'auto'/'dense'/'bitpack'/"
-                        "'pallas_bitpack' with a custom rule"
-                    )
                 self._rule = parsed
         self._resolved = (
             self._resolve_auto() if self.engine == "auto" else self.engine
         )
-        if self._rule is not None and self.mesh is not None:
-            # B3/S23 stays on the hard-wired fast paths; other rules run
-            # the generic evaluators — sharded via the explicit ring
-            # engine, or the sharded Pallas engine's overlap/pipeline
-            # forms (its kernel carries the generic rule tail).  Checked
-            # against the *resolved* engine so 'auto' runs that resolve
-            # to the Pallas engine get the same allowance as an explicit
-            # choice.
-            if self.shard_mode != "explicit" and not (
-                self.shard_mode in ("overlap", "pipeline")
-                and self._resolved == "pallas_bitpack"
-            ):
-                raise ValueError(
-                    "custom rules shard via the explicit ring engine (any "
-                    "engine) or the sharded Pallas engine's overlap/"
-                    f"pipeline forms (engine 'pallas_bitpack'); shard_mode "
-                    f"{self.shard_mode!r} with engine {self._resolved!r} "
-                    "is a Conway-specific program"
-                )
+        # Which engine may run with which rule, mesh, halo mode, shard
+        # mode and depth: one authority (gol_tpu.parallel.modes).  The
+        # geometry limits follow.
+        modes_mod.check_run(
+            self.engine,
+            self._resolved,
+            halo_mode=self.halo_mode,
+            shard_mode=self.shard_mode,
+            halo_depth=self.halo_depth,
+            custom_rule=self._rule is not None,
+            mesh=(
+                None if self.mesh is None
+                else "2d" if mesh_mod.COLS in self.mesh.axis_names
+                else "1d"
+            ),
+            reshard=self.reshard_at > 0,
+        )
         if self._resolved == "activity":
             self._init_activity()
         if self._resolved == "ooc":
             self._init_ooc()
-        # (engine, mode, depth) legality — ONE authority
-        # (gol_tpu.parallel.modes; the per-combo messages are pinned by
-        # tests/test_mode_plan.py).  Geometry limits follow.
-        from gol_tpu.parallel import modes as modes_mod
-
-        if self.mesh is None:
-            # The ooc tier is meshless by construction and reuses
-            # halo_depth as its per-visit generation depth k, so both
-            # mesh-coupled rejections exempt it (_init_ooc validated
-            # shard_mode already).
-            if self.halo_depth > 1 and self._resolved != "ooc":
-                raise ValueError(
-                    "halo_depth > 1 (temporal blocking) only applies to "
-                    "sharded runs; pass a mesh"
-                )
-            if self.shard_mode == "pipeline" and self._resolved != "ooc":
-                raise ValueError(
-                    "shard_mode 'pipeline' double-buffers ring exchanges "
-                    "across chunks, which only exist on sharded runs; "
-                    "pass a mesh"
-                )
-        elif self._resolved in modes_mod.ENGINE_MODES or (
-            self._resolved == "ooc"
-        ):
-            # For 'ooc' every cell rejects with the canonical
-            # mesh-none-only message (modes.mode_rejection).
-            modes_mod.check_combo(
-                self._resolved, self.shard_mode, self.halo_depth
-            )
         if self.halo_depth > 1 and self.mesh is not None:
             rows = self.mesh.shape.get(mesh_mod.ROWS, 1)
             cols = self.mesh.shape.get(mesh_mod.COLS, 1)
@@ -324,21 +269,6 @@ class GolRuntime:
                 self.halo_depth, shard_h, shard_w, two_d, units
             )
         if self.mesh is not None:
-            if self.halo_mode != "fresh":
-                raise ValueError(
-                    "stale_t0 (reference-compat) runs are single-device only; "
-                    "its blocks evolve independently so a mesh adds nothing"
-                )
-            if self.engine not in (
-                "auto", "dense", "bitpack", "pallas_bitpack", "activity"
-            ):
-                raise ValueError(
-                    f"engine {self.engine!r} has no sharded path; with a "
-                    "mesh use 'dense'/'auto' (shard_map+ppermute or "
-                    "auto-SPMD), 'bitpack' (packed shard_map+ppermute), "
-                    "'pallas_bitpack' (fused kernel per shard), or "
-                    "'activity' (gated worklist per shard)"
-                )
             shape = (self.geometry.global_height, self.geometry.global_width)
             if self._resolved == "pallas_bitpack":
                 if self.shard_mode in ("overlap", "pipeline"):
@@ -386,21 +316,10 @@ class GolRuntime:
                                 "shrink halo_depth or use shard_mode "
                                 "'explicit'"
                             )
-                from gol_tpu.ops import bitlife
-
-                if (
-                    mesh_mod.COLS in self.mesh.axis_names
-                    and self.halo_depth > bitlife.BITS
-                ):
-                    raise ValueError(
-                        "on a 2-D mesh the sharded Pallas engine's 1-word "
-                        f"column band supports halo_depth <= {bitlife.BITS},"
-                        f" got {self.halo_depth}"
-                    )
                 packed_mod.validate_packed_geometry(shape, self.mesh)
             elif self._resolved == "bitpack":
                 # Mode legality (incl. the auto-SPMD rejection) already
-                # ran through modes.check_combo — the overlap form is no
+                # ran through modes.check_run — the overlap form is no
                 # longer 1-D-only: depth-k interior/boundary splits cover
                 # both decompositions (gol_tpu.parallel.halo).
                 packed_mod.validate_packed_geometry(shape, self.mesh)
@@ -479,27 +398,6 @@ class GolRuntime:
         from gol_tpu.sparse import engine as sparse_engine
         from gol_tpu.sparse import mask as sparse_mask
 
-        if self.halo_mode != "fresh":
-            raise ValueError(
-                "engine 'activity' implements fresh halos only (the "
-                "stale_t0 compat mode reproduces a reference bug the "
-                "gated tier has no analog for)"
-            )
-        if self.rule is not None and self._rule is not None:
-            raise ValueError(
-                "engine 'activity' runs the B3/S23 fast paths; use "
-                "'dense'/'bitpack' with a custom rule"
-            )
-        if self.halo_depth != 1:
-            raise ValueError(
-                "engine 'activity' exchanges one-tile mask halos per "
-                f"generation; halo_depth must be 1, got {self.halo_depth}"
-            )
-        if self.mesh is not None and self.shard_mode != "explicit":
-            raise ValueError(
-                "the sharded activity engine has the explicit ring "
-                f"program only (got shard_mode {self.shard_mode!r})"
-            )
         h, w = self.geometry.global_height, self.geometry.global_width
         if self.activity_tile:
             tile = self.activity_tile
@@ -546,23 +444,7 @@ class GolRuntime:
         """
         from gol_tpu.ooc import planner as ooc_planner
         from gol_tpu.ops import bitlife
-        from gol_tpu.parallel import modes as modes_mod
 
-        if self.halo_mode != "fresh":
-            raise ValueError(
-                "engine 'ooc' implements fresh halos only (the stale_t0 "
-                "compat mode reproduces a reference bug the streaming "
-                "tier has no analog for)"
-            )
-        if self.rule is not None and self._rule is not None:
-            raise ValueError(
-                "engine 'ooc' streams the B3/S23 bit-packed band step; "
-                "use 'dense'/'bitpack' with a custom rule"
-            )
-        if self.shard_mode != "explicit":
-            # The canonical per-combo message — pinned like the rest of
-            # the matrix by tests/test_mode_plan.py.
-            raise ValueError(modes_mod.mode_rejection("ooc", self.shard_mode))
         w = self.geometry.global_width
         if w % bitlife.BITS != 0:
             raise ValueError(
@@ -574,12 +456,6 @@ class GolRuntime:
             raise ValueError(
                 "ooc_budget_mb and ooc_band_rows must be >= 0, got "
                 f"{self.ooc_budget_mb} / {self.ooc_band_rows}"
-            )
-        if self.reshard_at > 0:
-            raise ValueError(
-                "reshard_at replans onto a different mesh; the ooc tier "
-                "is meshless (its board already lives host-side — "
-                "checkpoint and resume instead)"
             )
         self._ooc_plan = ooc_planner.plan_bands(
             self.geometry.global_height,
@@ -794,8 +670,6 @@ class GolRuntime:
                 (top0, bottom0),
                 (self.geometry.num_ranks, steps),
             )
-        if self.halo_mode != "fresh":
-            raise ValueError(f"engine {name!r} implements fresh halos only")
         try:
             if name == "bitpack":
                 if self.mesh is not None:

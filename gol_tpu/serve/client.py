@@ -1,7 +1,7 @@
 """A tiny stdlib client for the serving tier (docs/SERVING.md).
 
-``urllib`` only — the client exists for the smoke drill, the chaos
-cells, and servebench, not as a product surface.  The one behavior that
+``urllib`` only — the client exists for the smoke drill and the chaos
+cells, not as a product surface.  The one behavior that
 matters is **idempotent resubmission**: callers pass their own request
 ``id``, and :meth:`SimClient.submit` retries connection errors (the
 server may be mid-supervised-restart) by resubmitting the same id —

@@ -196,8 +196,8 @@ class ReplicaHandle:
 class FleetFront:
     """The fleet state machine (transport-free core).
 
-    :class:`FleetServer` puts HTTP in front of it; the chaos cells and
-    servebench drive it in-process.  Thread model mirrors the serve
+    :class:`FleetServer` puts HTTP in front of it; the chaos cells
+    drive it in-process.  Thread model mirrors the serve
     scheduler: handler threads call :meth:`submit` / :meth:`result`
     through the lock; the owner's main loop calls :meth:`poll`.
     """

@@ -35,10 +35,7 @@ of it:
   anomalies; ``diff`` compares two runs (:mod:`gol_tpu.telemetry.
   summarize`).
 - Schema v6: ``chunk`` events carry a ``spans`` block decomposing the
-  host wall between force_ready fences (:class:`SpanClock`); ``python
-  -m gol_tpu.telemetry ledger ingest|show|check`` maintains the
-  cross-run perf ledger (:mod:`gol_tpu.telemetry.ledger`,
-  ``perf_history.jsonl``) with a >N%-regression CI gate; and
+  host wall between force_ready fences (:class:`SpanClock`); and
   ``--metrics-port`` serves the same in-process event stream as
   Prometheus text (:mod:`gol_tpu.telemetry.metrics`).
 - Schema v12: the serving tier threads a request-scoped span tree

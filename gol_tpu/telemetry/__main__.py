@@ -1,7 +1,6 @@
 """``python -m gol_tpu.telemetry
 {summarize <dir> | diff <a> <b> | watch <dir> | postmortem <dir> |
- trace <dir> [--request ID] [--perfetto out.json] [--slo FILE] |
- ledger ingest|show|check}``."""
+ trace <dir> [--request ID] [--perfetto out.json] [--slo FILE]}``."""
 
 import sys
 

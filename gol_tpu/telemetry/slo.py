@@ -16,11 +16,7 @@ degrades.  This module turns the per-request latency decompositions of
 
 Objectives are data, not code: ``--slo objectives.json`` loads a list of
 ``{"name", "metric", "target", "budget", "percentile"}`` objects, so a
-deployment tightens its targets without touching the repo.  servebench
-stamps the evaluation into SERVE_r*.json rows and the perf ledger gates
-on the burn-rate columns (kind ``slo``, direction ``lower``) — the
-regression gate fails when an SLO starts burning, not merely when
-throughput drops.
+deployment tightens its targets without touching the repo.
 """
 
 from __future__ import annotations
@@ -42,7 +38,7 @@ class SLO:
     percentile: float = 0.99
 
 
-#: The defaults servebench and the CLI evaluate when no objectives file
+#: The defaults the CLI evaluates when no objectives file
 #: is given: commit p99 under the scheduler's own deadline ceiling, and
 #: queue wait below half of end-to-end for the typical request.
 DEFAULT_SLOS = (

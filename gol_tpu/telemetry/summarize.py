@@ -31,16 +31,11 @@ the post-mortem):
   stats stream alone.
 
 ``diff <dir_a> <dir_b>`` compares two runs phase-by-phase and
-chunk-size-by-chunk-size — the missing tool behind BENCH_r* trajectory
-analysis (was: eyeballing two JSON blobs).
+chunk-size-by-chunk-size.
 
 Schema-v6 ``spans`` blocks render as a per-phase breakdown table
 (dispatch / block-until-ready / checkpoint / telemetry / preempt-poll
-host seconds summed over the run), and ``summarize --ledger FILE`` adds
-the cross-run **regression** anomaly: a run whose summary throughput
-sits more than the threshold below the perf ledger's best for its
-config fingerprint (:mod:`gol_tpu.telemetry.ledger`, which also owns
-the ``ledger ingest|show|check`` subcommands routed from here).
+host seconds summed over the run).
 
 Exit codes: 0 on success (anomalies are reported, not fatal — they are
 the tool's *output*), 2 on schema-invalid or unreadable input.
@@ -811,33 +806,10 @@ def render_run(run: Run, out) -> None:
         print(f"  ANOMALY: {flag}", file=out)
 
 
-def summarize(
-    directory: str,
-    out,
-    ledger_path: Optional[str] = None,
-    regress_threshold: Optional[float] = None,
-) -> int:
+def summarize(directory: str, out) -> int:
     runs = load_dir(directory)
-    ledger_records = None
-    if ledger_path:
-        # The cross-run regression anomaly (docs/OBSERVABILITY.md): a
-        # run whose summary throughput sits >threshold below the perf
-        # ledger's best for the same config fingerprint gets flagged.
-        from gol_tpu.telemetry import ledger as ledger_mod
-
-        ledger_records = ledger_mod.read_ledger(ledger_path)
     for run_id in sorted(runs):
         render_run(runs[run_id], out)
-        if ledger_records is not None:
-            from gol_tpu.telemetry import ledger as ledger_mod
-
-            kw = {}
-            if regress_threshold is not None:
-                kw["threshold"] = regress_threshold
-            for flag in ledger_mod.ledger_regression_flags(
-                runs[run_id], ledger_records, **kw
-            ):
-                print(f"  ANOMALY: {flag}", file=out)
     for m in load_manifests(directory):
         render_manifest(m, out)
     # Directory-level: supervised restarts span runs, so the storm
@@ -941,48 +913,9 @@ def main(argv=None) -> int:
     sub = p.add_subparsers(dest="command", required=True)
     ps = sub.add_parser("summarize", help="merge rank files, render tables")
     ps.add_argument("directory")
-    ps.add_argument(
-        "--ledger",
-        default=None,
-        metavar="FILE",
-        help="flag a regression anomaly when a run's throughput sits "
-        "below the perf ledger's best for its config fingerprint",
-    )
-    ps.add_argument(
-        "--regress-threshold", type=float, default=None, metavar="FRAC"
-    )
     pd = sub.add_parser("diff", help="compare two telemetry runs")
     pd.add_argument("dir_a")
     pd.add_argument("dir_b")
-    pl = sub.add_parser(
-        "ledger",
-        help="cross-run perf ledger: ingest artifacts, show trends, "
-        "gate regressions (default perf_history.jsonl)",
-    )
-    lsub = pl.add_subparsers(dest="ledger_command", required=True)
-    pli = lsub.add_parser(
-        "ingest", help="normalize artifact JSONs / telemetry dirs into "
-        "the ledger (idempotent)"
-    )
-    pli.add_argument("paths", nargs="+", metavar="PATH")
-    pls = lsub.add_parser("show", help="per-config trend tables")
-    plc = lsub.add_parser(
-        "check", help="exit 1 when the newest record of any config "
-        "regresses past the threshold (the CI gate)"
-    )
-    plc.add_argument(
-        "--threshold", type=float, default=None, metavar="FRAC",
-        help="regression fraction (default 0.20)",
-    )
-    plc.add_argument(
-        "--backend", default="tpu", metavar="NAME",
-        help="gated backend ('all' gates everything; default tpu — "
-        "CPU artifacts are curve shape only)",
-    )
-    for sp in (pli, pls, plc):
-        sp.add_argument(
-            "--ledger", dest="ledger_path", default=None, metavar="FILE"
-        )
     pt = sub.add_parser(
         "trace",
         help="rebuild per-request span trees: latency decomposition, "
@@ -1030,28 +963,7 @@ def main(argv=None) -> int:
     ns = p.parse_args(list(sys.argv[1:] if argv is None else argv))
     try:
         if ns.command == "summarize":
-            return summarize(
-                ns.directory,
-                sys.stdout,
-                ledger_path=ns.ledger,
-                regress_threshold=ns.regress_threshold,
-            )
-        if ns.command == "ledger":
-            from gol_tpu.telemetry import ledger as ledger_mod
-
-            path = ns.ledger_path or ledger_mod.DEFAULT_LEDGER
-            if ns.ledger_command == "ingest":
-                return ledger_mod.main_ingest(ns.paths, path, sys.stdout)
-            if ns.ledger_command == "show":
-                return ledger_mod.main_show(path, sys.stdout)
-            return ledger_mod.main_check(
-                path,
-                ns.threshold
-                if ns.threshold is not None
-                else ledger_mod.DEFAULT_THRESHOLD,
-                (ns.backend,),
-                sys.stdout,
-            )
+            return summarize(ns.directory, sys.stdout)
         if ns.command == "trace":
             from gol_tpu.telemetry import trace as trace_mod
 

@@ -247,8 +247,7 @@ def _percentile(sorted_vals: List[float], q: float) -> Optional[float]:
 def decomposition_percentiles(
     decomps: List[dict], qs=(0.50, 0.99)
 ) -> Dict[str, dict]:
-    """Per-phase percentiles over a trace set — the servebench row
-    columns and the table footer share this."""
+    """Per-phase percentiles over a trace set (the table footer)."""
     out: Dict[str, dict] = {}
     for phase in ("e2e_s",) + PHASES:
         vals = sorted(

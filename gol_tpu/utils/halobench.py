@@ -531,10 +531,7 @@ def main(argv=None) -> None:
                 else mesh_mod.make_mesh_1d()
             )
         if sweep_depths is not None:
-            from gol_tpu.telemetry import ledger as ledger_mod
-
             out = {
-                "header": ledger_mod.artifact_header("halobench"),
                 "note": (
                     "k-vs-MFU sweep of the ring chunk forms (PR 9): "
                     "step_s/updates_per_sec/mfu per (shard_mode, "
@@ -595,8 +592,6 @@ def main(argv=None) -> None:
                             ev.bench_row("halobench", {**body, "name": key})
             return
         out = measure(mesh, size, steps, engine)
-    from gol_tpu.telemetry import ledger as ledger_mod
-
     out.update(
         {
             "size": list(size) if isinstance(size, tuple) else size,
@@ -604,10 +599,6 @@ def main(argv=None) -> None:
             "mesh": dict(mesh.shape),
             "devices": len(mesh.devices.ravel()),
             "engine": engine,
-            # Satellite (PR 9): the module emitter stamps the common
-            # header too, so a bare `python -m gol_tpu.utils.halobench`
-            # capture ingests with zero sniffing like capture_artifacts'.
-            "header": ledger_mod.artifact_header("halobench"),
         }
     )
     print(json.dumps(out))

@@ -95,6 +95,14 @@ def available() -> bool:
     return _load() is not None
 
 
+def reload() -> bool:
+    """Forget the memoized load and probe again: for a process that built
+    the library after its first probe (which memoized the miss)."""
+    global _lib, _load_attempted
+    _lib, _load_attempted = None, False
+    return available()
+
+
 def format_world(block: np.ndarray, rank: int) -> bytes:
     """Native renderer; byte-identical to utils.io.format_world."""
     lib = _load()

@@ -248,8 +248,6 @@ def main(argv=None) -> None:
         # Process 0 owns the full curve (its devices lead the global list,
         # so it participates in every row, including the 1-device
         # baseline); it reports alone, like the reference's rank 0.
-        from gol_tpu.telemetry import ledger as ledger_mod
-
         print(
             json.dumps(
                 {
@@ -260,10 +258,6 @@ def main(argv=None) -> None:
                     "platform": jax.devices()[0].platform,
                     "processes": topo.process_count,
                     "rows": rows,
-                    # Satellite (PR 9): the module emitter stamps the
-                    # common header (capture_artifacts already does), so
-                    # a bare capture ingests with zero sniffing.
-                    "header": ledger_mod.artifact_header("scalebench"),
                 }
             )
         )

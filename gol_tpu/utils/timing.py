@@ -90,8 +90,7 @@ def time_best(fn, arg_factory, repeats: int = 3) -> float:
     ``arg_factory`` returns a fresh argument per call so donating functions
     never consume a buffer the next repeat needs.  One untimed call warms
     compilation; ``force_ready`` fences every timed call.  Shared by the
-    halo-latency and weak-scaling harnesses (bench.py deliberately chains
-    donated boards instead, so its 256 MB boards are never copied).
+    halo-latency and weak-scaling harnesses.
     """
     force_ready(fn(arg_factory()))
     best = float("inf")
@@ -105,27 +104,6 @@ def time_best(fn, arg_factory, repeats: int = 3) -> float:
         force_ready(out)
         best = min(best, time.perf_counter() - t0)
     return best
-
-
-def fit_overhead(walls: Dict[int, float]):
-    """Two-point per-invocation-overhead fit (r5 measurement discipline).
-
-    Wall time of one invocation of an n-step device loop is
-    ``T(n) = a + b*n``: ``a`` the per-invocation overhead (dispatch,
-    launch, fence) and ``b`` the device's per-step time.  Given best-wall samples at
-    two (or more — the fit uses the extremes) loop lengths, returns
-    ``(overhead_s, per_step_s)``.  Single-interval wall rates conflate
-    the two and under-report the chip *differently per config*, so every
-    cross-config conclusion must come from ``b``, never from walls
-    (BASELINE.md r5).  One definition shared by ``bench.py`` and the
-    ``benchmarks/exp_*_fit.py`` scripts so the artifacts cannot
-    disagree on the arithmetic.
-    """
-    if len(walls) < 2:
-        raise ValueError(f"need >= 2 loop lengths to fit, got {walls}")
-    (n1, t1), *_, (n2, t2) = sorted(walls.items())
-    b = (t2 - t1) / (n2 - n1)
-    return t1 - b * n1, b
 
 
 @contextlib.contextmanager

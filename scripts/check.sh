@@ -20,9 +20,7 @@
 #                       v5 activity telemetry present)
 #   8. obs smoke       (run with --metrics-port + v6 spans: scrape the
 #                       live Prometheus endpoint mid-run, reconcile it
-#                       with the JSONL, summarize the span table, and
-#                       run `ledger check` against the committed
-#                       chip-history regression gate)
+#                       with the JSONL, and summarize the span table)
 #   9. reshard smoke   (elastic meshes: a 2-D-block sharded snapshot
 #                       resumed on a 1-D ring must be bit-equal to a
 #                       straight run, with a non-identity plan and the

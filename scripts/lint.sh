@@ -9,9 +9,9 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 if command -v ruff >/dev/null 2>&1; then
-    exec ruff check gol_tpu tests benchmarks bench.py
+    exec ruff check gol_tpu tests
 fi
 
 echo "lint: ruff not installed; falling back to compile-only check" >&2
-python -m compileall -q gol_tpu tests benchmarks bench.py
+python -m compileall -q gol_tpu tests
 echo "lint: compile check passed (install ruff for the full lint)"

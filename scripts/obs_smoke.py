@@ -1,4 +1,4 @@
-"""Observability smoke: metrics endpoint + spans + ledger gate, end to end.
+"""Observability smoke: metrics endpoint + spans, end to end.
 
 check.sh stage [8/9] (docs/OBSERVABILITY.md).  Drives the real CLI in a
 subprocess with ``--metrics-port 0`` and asserts the continuous-
@@ -11,9 +11,7 @@ observability surface end to end:
    recorded — one emission feeds both surfaces, so they cannot drift);
 3. every chunk event carries a schema-v6 ``spans`` block whose
    dispatch+ready seconds match the chunk's fenced wall;
-4. ``summarize`` renders the span phase-breakdown table and exits 0;
-5. ``ledger check`` passes against the committed chip-round history
-   ``tests/data/ledger/history.jsonl`` (the CI regression gate).
+4. ``summarize`` renders the span phase-breakdown table and exits 0.
 """
 
 from __future__ import annotations
@@ -145,27 +143,10 @@ def main() -> int:
             )
             return 1
 
-    gate = subprocess.run(
-        [
-            sys.executable, "-m", "gol_tpu.telemetry", "ledger", "check",
-            "--ledger", str(REPO / "tests" / "data" / "ledger" / "history.jsonl"),
-        ],
-        env=env,
-        capture_output=True,
-        text=True,
-        cwd=REPO,
-    )
-    if gate.returncode != 0:
-        print(
-            f"FAIL: ledger check rc={gate.returncode}\n{gate.stdout}"
-            f"{gate.stderr}"
-        )
-        return 1
-
     print(
         f"obs smoke OK: scraped generation {mid_gen} mid-run "
         f"(reconciles with {len(chunks)} chunk events), spans on every "
-        f"chunk, summarize renders the phase table, ledger gate green"
+        f"chunk, summarize renders the phase table"
     )
     return 0
 

@@ -494,35 +494,6 @@ def test_cli_batch_auto_resume_total_target(tmp_path, capsys):
     assert "auto-resume: generation 8" in out
 
 
-# -- batchbench --------------------------------------------------------------
-
-
-def test_batchbench_writes_artifact(tmp_path):
-    from benchmarks import batchbench
-
-    out = tmp_path / "BATCH_test.json"
-    rc = batchbench.main([
-        "--size", "32", "--iters", "8", "--bs", "1,2",
-        "--engine", "bitpack", "--repeats", "1", "--out", str(out),
-    ])
-    assert rc == 0
-    data = json.loads(out.read_text())
-    assert data["backend"] == "cpu"
-    assert [r["B"] for r in data["rows"]] == [1, 2]
-    for row in data["rows"]:
-        assert row["per_world_speedup_vs_sequential"] > 0
-        assert "device_fit" in row
-
-
-def test_committed_batch_artifact_is_valid():
-    import pathlib
-
-    path = pathlib.Path(__file__).parent.parent / "BATCH_r06.json"
-    data = json.loads(path.read_text())
-    assert data["rows"] and "command" in data
-    assert all("per_world_speedup_vs_sequential" in r for r in data["rows"])
-
-
 # -- verifier ----------------------------------------------------------------
 
 

@@ -99,6 +99,111 @@ def test_depth_vs_shard_extent(two_d, shard_h, shard_w, k, ok):
             modes.check_depth(k, shard_h, shard_w, two_d)
 
 
+# -- check_run: the one entry point the runtime and the CLI call -------------
+#
+# Each case is one cell: (requested engine, resolved engine, knobs) and
+# the message it must raise, or None for a cell that has a program.
+
+
+def _cell(id_, engine, resolved, match=None, **kw):
+    return pytest.param(engine, resolved, kw, match, id=id_)
+
+
+LEGALITY_MATRIX = [
+    # Cells with a program.
+    _cell("activity-meshless-ignores-mode", "activity", "activity",
+          shard_mode="overlap"),
+    _cell("auto-pallas-2d-pipeline-custom-rule", "auto", "pallas_bitpack",
+          mesh="2d", shard_mode="pipeline", halo_depth=8, custom_rule=True),
+    _cell("pallas-bitpack-2d-depth-at-column-band", "pallas_bitpack",
+          "pallas_bitpack", mesh="2d", halo_depth=32),
+    _cell("dense-meshless-stale", "dense", "dense", halo_mode="stale_t0"),
+    _cell("auto-meshless-stale", "auto", "dense", halo_mode="stale_t0"),
+    _cell("pallas-meshless", "pallas", "pallas"),
+    _cell("dense-2d-auto-spmd", "dense", "dense", mesh="2d",
+          shard_mode="auto"),
+    _cell("bitpack-1d-explicit-custom-rule", "bitpack", "bitpack",
+          mesh="1d", custom_rule=True),
+    _cell("batch-auto-1d", "auto", "auto", mesh="1d", batch=True),
+    _cell("batch-pallas-bitpack-meshless", "pallas_bitpack",
+          "pallas_bitpack", batch=True),
+    # Unknown names and depths.
+    _cell("unknown-engine", "life", "life", "unknown engine"),
+    _cell("unknown-halo-mode", "dense", "dense", "unknown halo_mode",
+          halo_mode="frozen"),
+    _cell("unknown-shard-mode-meshless", "bitpack", "bitpack",
+          "unknown shard_mode", shard_mode="ring"),
+    _cell("negative-depth-meshless", "bitpack", "bitpack", "must be >= 1",
+          halo_depth=-1),
+    # Custom rules.
+    _cell("custom-rule-stale-bitpack", "bitpack", "bitpack",
+          "no stale_t0 reference-compat mode", halo_mode="stale_t0",
+          custom_rule=True),
+    _cell("custom-rule-pallas-1d", "pallas", "pallas", "hard-wired",
+          mesh="1d", custom_rule=True),
+    _cell("custom-rule-dense-2d-pipeline", "dense", "dense",
+          "Conway-specific", mesh="2d", shard_mode="pipeline",
+          halo_depth=2, custom_rule=True),
+    _cell("custom-rule-auto-to-bitpack-overlap", "auto", "bitpack",
+          "Conway-specific", mesh="1d", shard_mode="overlap",
+          custom_rule=True),
+    # The activity tier.
+    _cell("activity-1d-stale", "activity", "activity", "gated tier",
+          mesh="1d", halo_mode="stale_t0"),
+    _cell("activity-1d-custom-rule", "activity", "activity",
+          "B3/S23 fast paths", mesh="1d", custom_rule=True),
+    _cell("activity-2d-depth-2", "activity", "activity",
+          "halo_depth must be 1", mesh="2d", halo_depth=2),
+    _cell("activity-2d-pipeline", "activity", "activity",
+          "explicit ring program only", mesh="2d", shard_mode="pipeline"),
+    _cell("activity-meshless-pipeline", "activity", "activity",
+          "pass a mesh", shard_mode="pipeline"),
+    # The out-of-core tier.
+    _cell("ooc-stale", "ooc", "ooc", "streaming tier has no analog",
+          halo_mode="stale_t0"),
+    _cell("ooc-custom-rule", "ooc", "ooc", "bit-packed band step",
+          custom_rule=True),
+    _cell("ooc-meshless-auto-mode", "ooc", "ooc", "no sharded ring program",
+          shard_mode="auto"),
+    _cell("ooc-reshard", "ooc", "ooc", "replans onto a different mesh",
+          reshard=True),
+    _cell("ooc-2d", "ooc", "ooc", "no sharded ring program", mesh="2d"),
+    # Meshless runs and meshes.
+    _cell("dense-meshless-depth-2", "dense", "dense",
+          "only applies to sharded runs", halo_depth=2),
+    _cell("bitpack-meshless-stale", "bitpack", "bitpack",
+          "engine 'bitpack' implements fresh halos only",
+          halo_mode="stale_t0"),
+    _cell("pallas-bitpack-meshless-stale", "pallas_bitpack",
+          "pallas_bitpack",
+          "engine 'pallas_bitpack' implements fresh halos only",
+          halo_mode="stale_t0"),
+    _cell("auto-1d-stale", "auto", "dense", "single-device only",
+          mesh="1d", halo_mode="stale_t0"),
+    _cell("pallas-2d", "pallas", "pallas", "no sharded path", mesh="2d"),
+    _cell("auto-pallas-2d-past-column-band", "auto", "pallas_bitpack",
+          "column band supports halo_depth <= 32", mesh="2d",
+          halo_depth=64),
+    # Batched runs (the CLI's --batch).
+    _cell("batch-bitpack-stale", "bitpack", "bitpack",
+          "--batch runs fresh halos only", halo_mode="stale_t0",
+          batch=True),
+    _cell("batch-bitpack-rule", "bitpack", "bitpack",
+          "single-world feature", custom_rule=True, batch=True),
+    _cell("batch-pallas-bitpack-2d", "pallas_bitpack", "pallas_bitpack",
+          "world axis", mesh="2d", batch=True),
+]
+
+
+@pytest.mark.parametrize("engine,resolved,kw,match", LEGALITY_MATRIX)
+def test_check_run_legality_matrix(engine, resolved, kw, match):
+    if match is None:
+        modes.check_run(engine, resolved, **kw)  # must not raise
+    else:
+        with pytest.raises(ValueError, match=match):
+            modes.check_run(engine, resolved, **kw)
+
+
 # -- the runtime validates THROUGH the matrix --------------------------------
 
 

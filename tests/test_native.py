@@ -23,8 +23,7 @@ def build_native():
             capture_output=True,
         )
     # Reset the lazy loader so this module sees a lib built after import.
-    native._lib = None
-    native._load_attempted = False
+    native.reload()
     yield
 
 

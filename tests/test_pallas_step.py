@@ -1,8 +1,8 @@
 """Pallas fused kernel vs. the oracle — interpret mode on the CPU backend.
 
-The same kernel code compiles through Mosaic on a real TPU (exercised by
-bench.py / the driver); interpret mode checks semantics: DMA halo layout,
-aligned offsets, lane-roll column wrap, rule fusion.
+The same kernel code compiles through Mosaic on a real TPU; interpret
+mode checks semantics: DMA halo layout, aligned offsets, lane-roll column
+wrap, rule fusion.
 """
 
 import jax.numpy as jnp

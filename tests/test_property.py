@@ -776,6 +776,7 @@ def test_ooc_streamed_matches_oracle_any_banding(
     parity and dead-band skipping — remainder bands included (any h not
     a multiple of the band height exercises the absorbing last band)."""
     w = 32 * words
+    h = max(h, 2 * depth + 1)  # planner floor: more rows than two k-shells
     band = max(depth, min(band, h))  # planner floor: band height >= k
     board = _board(h, w, seed)
     got = _ooc_run(board, depth, band, schedule, parity, skip)

@@ -119,18 +119,3 @@ def test_peak_is_keyed_by_device_kind():
         roofline.vpu_lane_ops()
     with pytest.raises(ValueError, match="no VPU peak"):
         roofline.roofline_2d(1e12, tile=1024, k=1)
-
-
-def test_fit_overhead_two_point():
-    """The r5 per-invocation-overhead fit: T(n) = a + b*n recovered exactly from
-    two points (shared by bench.py and the exp_*_fit scripts)."""
-    from gol_tpu.utils.timing import fit_overhead
-
-    a, b = fit_overhead({1024: 0.25 + 1024 * 1e-4, 8192: 0.25 + 8192 * 1e-4})
-    assert a == pytest.approx(0.25)
-    assert b == pytest.approx(1e-4)
-    # More than two lengths: the fit uses the extremes.
-    a, b = fit_overhead({10: 1.1, 20: 1.2, 110: 2.1})
-    assert a == pytest.approx(1.0)
-    with pytest.raises(ValueError, match="loop lengths"):
-        fit_overhead({100: 1.0})
